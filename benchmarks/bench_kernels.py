@@ -1,4 +1,4 @@
-"""Kernel micro-benchmarks: Pallas (interpret on CPU) vs jnp oracle.
+"""Kernel micro-benchmarks: Pallas in interpret mode vs jnp oracle.
 
 On CPU the interpret-mode kernel is slower than fused XLA — the number that
 matters here is the ORACLE column (the jnp path the dry-run lowers) and the
@@ -41,8 +41,8 @@ def run():
     v = jax.random.normal(ks[2], (BK, S, hd), jnp.float32)
     f = jax.jit(lambda q, k, v: flash_attention_ref(q, k, v, scale=0.125))
     rows["flash_ref_us"] = round(_time(f, q, k, v), 1)
-    g = jax.jit(lambda q, k, v: flash_attention_bkg(q, k, v, scale=0.125,
-                                                    bq=128, bk=128))
+    g = jax.jit(lambda q, k, v: flash_attention_bkg(
+        q, k, v, scale=0.125, bq=128, bk=128, interpret=True))
     rows["flash_pallas_interp_us"] = round(_time(g, q, k, v), 1)
     rows["flash_gflops"] = round(
         4 * BK * G * S * S * hd / 1e9, 2)
@@ -57,7 +57,7 @@ def run():
     u = jax.random.normal(ks[4], (BH, hd2), jnp.float32) * 0.1
     f = jax.jit(wkv6_ref)
     rows["wkv6_ref_us"] = round(_time(f, r, kk, vv, lw, u), 1)
-    g = jax.jit(lambda *a: wkv6_chunked(*a, chunk=64))
+    g = jax.jit(lambda *a: wkv6_chunked(*a, chunk=64, interpret=True))
     rows["wkv6_pallas_interp_us"] = round(_time(g, r, kk, vv, lw, u), 1)
 
     B, C = 4, 512
@@ -66,7 +66,8 @@ def run():
     b = jax.random.normal(ks[1], (B, S, C))
     f = jax.jit(rglru_scan_ref)
     rows["rglru_ref_us"] = round(_time(f, a, b), 1)
-    g = jax.jit(lambda a, b: rglru_scan_blocked(a, b, bt=128, bc=256))
+    g = jax.jit(lambda a, b: rglru_scan_blocked(a, b, bt=128, bc=256,
+                                                interpret=True))
     rows["rglru_pallas_interp_us"] = round(_time(g, a, b), 1)
 
     emit("bench_kernels", rows["flash_ref_us"], rows)

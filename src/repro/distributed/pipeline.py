@@ -65,9 +65,8 @@ def pipeline_apply(stage_params, x, stage_fn: Callable, mesh: Mesh,
         return out.reshape(B, *xl.shape[1:])
 
     pspec = jax.tree.map(lambda t: P(axis), stage_params)
-    from repro.distributed.compat import shard_map
-    return shard_map(
-        body, mesh=mesh,
+    return jax.shard_map(
+        body, mesh=mesh, check_vma=False,
         in_specs=(pspec, P()),
         out_specs=P(),
     )(stage_params, x)

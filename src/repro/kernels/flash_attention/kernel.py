@@ -14,11 +14,14 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.platform import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -87,7 +90,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                      "interpret"))
 def flash_attention_bkg(q, k, v, *, scale: float, softcap: float = 0.0,
                         window: int = 0, causal: bool = True, bq: int = 128,
-                        bk: int = 128, interpret: bool = True):
+                        bk: int = 128, interpret: Optional[bool] = None):
     """q: (BK, Sq, G, hd); k,v: (BK, Skv, hd) -> (BK, Sq, G, hd)."""
     BK, Sq, G, hd = q.shape
     Skv = k.shape[1]
@@ -115,5 +118,5 @@ def flash_attention_bkg(q, k, v, *, scale: float, softcap: float = 0.0,
             pltpu.VMEM((bq * G,), jnp.float32),
             pltpu.VMEM((bq * G, hd), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

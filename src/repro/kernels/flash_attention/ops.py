@@ -3,6 +3,8 @@ kernel layout and plugs into ``repro.models.attention.set_attention_impl``.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 
@@ -11,7 +13,7 @@ from repro.kernels.flash_attention.kernel import flash_attention_bkg
 
 def flash_attention(q, k, v, *, window: int = 0, softcap: float = 0.0,
                     scale: float, causal: bool = True, bq: int = 128,
-                    bk: int = 128, interpret: bool = True):
+                    bk: int = 128, interpret: Optional[bool] = None):
     """q: (B,S,K,G,hd); k,v: (B,Skv,K,hd) -> (B,S,K,G,hd)."""
     B, Sq, K, G, hd = q.shape
     Skv = k.shape[1]

@@ -1,12 +1,15 @@
 """Model-facing wrapper for the wkv6 kernel."""
 from __future__ import annotations
 
+from typing import Optional
+
 import jax.numpy as jnp
 
 from repro.kernels.rwkv6_chunk.kernel import wkv6_chunked
 
 
-def wkv6(r, k, v, logw, u, *, chunk: int = 64, interpret: bool = True):
+def wkv6(r, k, v, logw, u, *, chunk: int = 64,
+         interpret: Optional[bool] = None):
     """r,k,v,logw: (B,S,H,hd); u: (H,hd) -> (B,S,H,hd)."""
     B, S, H, hd = r.shape
     fold = lambda t: t.astype(jnp.float32).transpose(0, 2, 1, 3) \

@@ -1,29 +1,22 @@
 """Production mesh builders (functions — importing never touches devices).
 
-``make_mesh`` wraps ``jax.make_mesh`` across the API drift around
-``jax.sharding.AxisType``: newer jax versions accept (and eventually
-expect) ``axis_types=``, while e.g. 0.4.37 has neither the enum nor the
-keyword.  All repo code and tests build meshes through this helper so a
-jax upgrade/downgrade never breaks mesh construction again.
+All repo code and tests build meshes through ``make_mesh``.
 """
 from __future__ import annotations
 
 from typing import Optional, Sequence
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               devices: Optional[Sequence] = None):
-    """Version-compatible ``jax.make_mesh``: passes ``axis_types`` (all
-    ``Auto``) only when the installed jax still exposes the enum."""
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        kwargs["axis_types"] = (axis_type.Auto,) * len(axis_names)
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+    """``jax.make_mesh`` with every axis ``Auto``: the models place arrays
+    with sharding constraints and leave propagation to the compiler."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -1,6 +1,9 @@
 """Serving launcher: ``PYTHONPATH=src python -m repro.launch.serve
---arch <id> [--requests N] [--slots K]`` — continuous-batching engine over
-the reduced config (CPU) or the full config on a real fleet.
+--arch <id> [--requests N] [--slots K] [--full]`` — continuous-batching
+engine over the arch's smoke config, or with ``--full`` its published
+config (gemma3-1b at full width fits one TPU v5e chip).  Runs on whatever
+backend JAX picks; the printed line names that device, and its time
+includes compilation.
 """
 from __future__ import annotations
 
@@ -11,8 +14,21 @@ import jax
 import numpy as np
 
 from repro.configs.base import get_config, get_smoke_config, list_archs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.serving.engine import Request, ServingEngine
+
+
+def make_requests(vocab_size: int, n: int, max_new: int,
+                  seed: int = 0) -> list:
+    """``n`` requests with seeded prompts of 3-8 token ids in
+    ``[2, vocab_size)`` (0 and 1 are left out: 1 is the engine's EOS)."""
+    rng = np.random.default_rng(seed)
+    reqs = []
+    for i in range(n):
+        prompt = rng.integers(2, vocab_size, size=int(rng.integers(3, 9)))
+        reqs.append(Request(i, prompt.astype(np.int32), max_new=max_new))
+    return reqs
 
 
 def main():
@@ -25,22 +41,21 @@ def main():
     ap.add_argument("--full", action="store_true")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch) if args.full else get_smoke_config(args.arch)
     params = init_params(jax.random.PRNGKey(0), cfg)
     eng = ServingEngine(cfg, params, n_slots=args.slots,
                         max_len=args.max_len)
-    rng = np.random.default_rng(0)
     t0 = time.perf_counter()
-    for i in range(args.requests):
-        prompt = rng.integers(2, cfg.vocab_size,
-                              size=int(rng.integers(3, 9)))
-        eng.submit(Request(i, prompt.astype(np.int32),
-                           max_new=args.max_new))
+    for req in make_requests(cfg.vocab_size, args.requests, args.max_new):
+        eng.submit(req)
     done = eng.run_until_done()
     dt = time.perf_counter() - t0
     toks = sum(len(r.tokens_out) for r in done)
-    print(f"{len(done)} requests, {toks} tokens, {dt:.1f}s "
-          f"({toks/dt:.1f} tok/s)")
+    dev = jax.devices()[0]
+    print(f"{len(done)} requests, {toks} tokens, {dt:.1f}s with compilation "
+          f"({toks/dt:.1f} tok/s) on {dev.platform} {dev.device_kind} "
+          f"x{len(jax.devices())}")
 
 
 if __name__ == "__main__":

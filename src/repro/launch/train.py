@@ -12,6 +12,7 @@ import argparse
 import numpy as np
 
 from repro.configs.base import get_config, get_smoke_config, list_archs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import make_optimizer
 from repro.train.loop import TrainLoop
 
@@ -31,6 +32,7 @@ def main():
                          "accelerator fleet)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch) if args.full else get_smoke_config(args.arch)
     opt = make_optimizer(cfg.optimizer)
     print(f"arch={args.arch} params={cfg.param_count()/1e6:.1f}M "

@@ -250,9 +250,8 @@ def moe_sharded(params, x, cfg: ModelConfig, decode: bool = False):
         aux = jax.lax.pmean(aux, tuple(mesh.axis_names))
         return y, aux
 
-    from repro.distributed.compat import shard_map
-    y, aux = shard_map(
-        body, mesh=mesh,
+    y, aux = jax.shard_map(
+        body, mesh=mesh, check_vma=False,
         in_specs=(in_spec, P(), wg_spec, wg_spec, wd_spec),
         out_specs=(out_spec, P()),
     )(x, params["router"], params["w_gate"], params["w_up"],

@@ -69,9 +69,11 @@ class ServingEngine:
             # only slot i's lane matters; others decode a dummy token into
             # their current position (masked by per-slot positions)
             logits, self.cache = self._decode(
-                self.params, self.cache, tok, jnp.asarray(self.pos[i]))
+                self.params, self.cache, tok, np.int32(self.pos[i]))
             self.pos[i] += 1
-        self._last_logits = logits
+        # logits after the prompt's last token, every lane; slot i's row is
+        # the request's next-token distribution
+        self.last_prompt_logits = logits
 
     # ------------------------------------------------------------------
     def step(self) -> int:
@@ -85,9 +87,11 @@ class ServingEngine:
             r = self.slots[i]
             toks[i, 0] = r.tokens_out[-1] if r.tokens_out else \
                 (r.prompt[-1] if len(r.prompt) else 0)
-        pos = int(max(self.pos[i] for i in active))
+        # positions go in as strongly typed int32 here and in _feed_prompt,
+        # so both call sites share one compiled decode program
+        pos = np.int32(max(self.pos[i] for i in active))
         logits, self.cache = self._decode(
-            self.params, self.cache, jnp.asarray(toks), jnp.asarray(pos))
+            self.params, self.cache, jnp.asarray(toks), pos)
         nxt = np.asarray(jnp.argmax(logits[:, -1].astype(jnp.float32),
                                     axis=-1))
         for i in active:

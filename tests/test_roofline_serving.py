@@ -60,6 +60,22 @@ def test_serving_engine_completes():
     assert all(1 <= len(r.tokens_out) <= 4 for r in done)
 
 
+def test_serving_engine_compiles_decode_once():
+    """Prompt feeding and batched decode steps pass the position with the
+    same dtype and weak type, so they share one compiled program."""
+    from repro.configs.base import get_smoke_config
+    from repro.models import init_params
+    from repro.serving.engine import Request, ServingEngine
+    cfg = get_smoke_config("gemma3-1b")
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    eng = ServingEngine(cfg, params, n_slots=2, max_len=32)
+    for i, n in enumerate((3, 5, 4)):
+        eng.submit(Request(i, np.arange(2, 2 + n, dtype=np.int32),
+                           max_new=3))
+    assert len(eng.run_until_done(max_steps=100)) == 3
+    assert eng._decode._cache_size() == 1
+
+
 def test_mesh_planner_bridge():
     from repro.core.planner import (LayoutCandidate, mesh_topology,
                                     plan_mesh_layout, score_layout)
