@@ -9,6 +9,13 @@ hardware-aligned tiles; fully-masked KV blocks are skipped with ``pl.when``
 
 Layout: q (BK, Sq, G, hd); k,v (BK, Skv, hd) — one grid row per (batch x
 kv-head), GQA group folded into the q-block rows.
+
+Precision: blocks are upcast to f32, and the two dots run at the MXU's
+default precision, as XLA's f32 dots on a TPU do: operands rounded to
+bf16, products accumulated in f32.  On a TPU v5e at gemma3-1b widths the
+output is within about 0.4% of max |out| of an f32 reference
+(``chip_smoke.py``, kernels phase).  The interpreter on the CPU computes
+the dots in full f32, so CPU tests cannot see this.
 """
 from __future__ import annotations
 

@@ -8,6 +8,13 @@ around one reference per row tile (``_intra``) so f32 never overflows.
 
 Layout: r,k,v,logw: (BH, S, hd) f32; u: (BH, 1, hd); grid (BH, S/c); state
 scratch (hd, hd), transposed.
+
+Precision: inputs, state and accumulation are f32, but the dots run at the
+MXU's default precision, as XLA's f32 dots on a TPU do: operands rounded
+to bf16.  On a TPU v5e at rwkv6-7b head width the output is within about
+0.4% of max |y| of the exact scan (``chip_smoke.py``, kernels phase).  The
+interpreter on the CPU computes the dots in full f32, so CPU tests cannot
+see this.
 """
 from __future__ import annotations
 

@@ -2,13 +2,12 @@
 --arch <id> [--requests N] [--slots K] [--full]`` — continuous-batching
 engine over the arch's smoke config, or with ``--full`` its published
 config (gemma3-1b at full width fits one TPU v5e chip).  Runs on whatever
-backend JAX picks; the printed line names that device, and its time
-includes compilation.
+backend JAX picks; the printed line names that device.  It prints no
+timing: a clock around this loop would count compilation and set-up.
 """
 from __future__ import annotations
 
 import argparse
-import time
 
 import jax
 import numpy as np
@@ -19,11 +18,10 @@ from repro.models import init_params
 from repro.serving.engine import Request, ServingEngine
 
 
-def make_requests(vocab_size: int, n: int, max_new: int,
-                  seed: int = 0) -> list:
-    """``n`` requests with seeded prompts of 3-8 token ids in
-    ``[2, vocab_size)`` (0 and 1 are left out: 1 is the engine's EOS)."""
-    rng = np.random.default_rng(seed)
+def make_requests(vocab_size: int, n: int, max_new: int) -> list:
+    """``n`` requests with prompts of 3-8 token ids in ``[2, vocab_size)``
+    drawn from seed 0 (0 and 1 are left out: 1 is the engine's EOS)."""
+    rng = np.random.default_rng(0)
     reqs = []
     for i in range(n):
         prompt = rng.integers(2, vocab_size, size=int(rng.integers(3, 9)))
@@ -46,16 +44,13 @@ def main():
     params = init_params(jax.random.PRNGKey(0), cfg)
     eng = ServingEngine(cfg, params, n_slots=args.slots,
                         max_len=args.max_len)
-    t0 = time.perf_counter()
     for req in make_requests(cfg.vocab_size, args.requests, args.max_new):
         eng.submit(req)
     done = eng.run_until_done()
-    dt = time.perf_counter() - t0
     toks = sum(len(r.tokens_out) for r in done)
     dev = jax.devices()[0]
-    print(f"{len(done)} requests, {toks} tokens, {dt:.1f}s with compilation "
-          f"({toks/dt:.1f} tok/s) on {dev.platform} {dev.device_kind} "
-          f"x{len(jax.devices())}")
+    print(f"{len(done)} requests, {toks} tokens on {dev.platform} "
+          f"{dev.device_kind} x{len(jax.devices())}")
 
 
 if __name__ == "__main__":

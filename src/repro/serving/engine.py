@@ -64,16 +64,30 @@ class ServingEngine:
                 self._feed_prompt(i, req)
 
     def _feed_prompt(self, i: int, req: Request):
+        logits = None
         for t in req.prompt:
             tok = jnp.full((self.n_slots, 1), int(t), jnp.int32)
-            # only slot i's lane matters; others decode a dummy token into
-            # their current position (masked by per-slot positions)
+            # only slot i's lane matters; the others decode a dummy token
+            # at slot i's position, which corrupts a busy lane (one scalar
+            # position per batch: ROADMAP queue 2)
             logits, self.cache = self._decode(
                 self.params, self.cache, tok, np.int32(self.pos[i]))
             self.pos[i] += 1
-        # logits after the prompt's last token, every lane; slot i's row is
-        # the request's next-token distribution
-        self.last_prompt_logits = logits
+        # the first token comes from the prompt's last logits; decode steps
+        # then feed it back at position len(prompt)
+        if logits is not None:
+            self._emit(i, int(jnp.argmax(logits[i, -1].astype(jnp.float32))))
+
+    def _emit(self, i: int, t: int):
+        """Slot i's request takes token t; the slot frees when it is done."""
+        r = self.slots[i]
+        r.tokens_out.append(t)
+        self.budget[i] -= 1
+        if t == self.eos_id or self.budget[i] <= 0 or \
+                self.pos[i] >= self.max_len - 1:
+            r.done = True
+            self.completed.append(r)
+            self.slots[i] = None
 
     # ------------------------------------------------------------------
     def step(self) -> int:
@@ -85,8 +99,7 @@ class ServingEngine:
         toks = np.zeros((self.n_slots, 1), np.int32)
         for i in active:
             r = self.slots[i]
-            toks[i, 0] = r.tokens_out[-1] if r.tokens_out else \
-                (r.prompt[-1] if len(r.prompt) else 0)
+            toks[i, 0] = r.tokens_out[-1] if r.tokens_out else 0
         # positions go in as strongly typed int32 here and in _feed_prompt,
         # so both call sites share one compiled decode program
         pos = np.int32(max(self.pos[i] for i in active))
@@ -95,16 +108,8 @@ class ServingEngine:
         nxt = np.asarray(jnp.argmax(logits[:, -1].astype(jnp.float32),
                                     axis=-1))
         for i in active:
-            r = self.slots[i]
-            t = int(nxt[i])
-            r.tokens_out.append(t)
             self.pos[i] += 1
-            self.budget[i] -= 1
-            if t == self.eos_id or self.budget[i] <= 0 or \
-                    self.pos[i] >= self.max_len - 1:
-                r.done = True
-                self.completed.append(r)
-                self.slots[i] = None
+            self._emit(i, int(nxt[i]))
         return len(active)
 
     def run_until_done(self, max_steps: int = 10_000) -> List[Request]:

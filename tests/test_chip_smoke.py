@@ -4,6 +4,7 @@ import importlib.util
 from pathlib import Path
 
 import jax
+import jax.numpy as jnp
 import pytest
 
 from repro.configs.base import get_smoke_config
@@ -31,6 +32,25 @@ def test_serve_phase_at_smoke_config(chip_smoke):
     assert rep["requests"] == chip_smoke.N_REQUESTS + 1
     assert rep["decode_compiles"] == 1
     assert rep["logits_max_abs_diff"] == 0.0     # exact on the CPU
+    assert rep["alone_argmax_agree"] == rep["alone_tokens"] > 1
+
+
+def test_kernel_phase_at_small_widths(chip_smoke):
+    """The chip's kernel checks, at small widths in the interpreter."""
+    small = {
+        "flash_attention_f32": dict(dtype=jnp.float32, BK=1, S=256, G=2,
+                                    hd=128, window=128),
+        "flash_attention_bf16": dict(dtype=jnp.bfloat16, BK=1, S=256, G=2,
+                                     hd=128, window=128),
+        "rglru_scan": dict(B=1, S=256, C=256),
+        "wkv6": dict(BH=2, S=128, hd=64),
+    }
+    assert set(small) == set(chip_smoke.KERNEL_CASES)
+    cases = {name: (build, small[name], tol)
+             for name, (build, _, tol) in chip_smoke.KERNEL_CASES.items()}
+    rep = chip_smoke.kernel_phase(jax.devices()[0], cases)
+    for name, k in rep.items():
+        assert 0.0 <= k["frac"] <= k["tol"], name
 
 
 def test_flood_phase_keeps_outputs_on_the_device(chip_smoke):

@@ -76,6 +76,28 @@ def test_serving_engine_compiles_decode_once():
     assert eng._decode._cache_size() == 1
 
 
+def test_serving_engine_serves_greedy_continuation_of_prompt():
+    """A lone request's tokens are the greedy continuation of its prompt:
+    the first comes from the prompt's logits, each later one from the
+    sequence served so far (checked against prefill over that sequence)."""
+    from repro.configs.base import get_smoke_config
+    from repro.models import forward_prefill, init_params
+    from repro.serving.engine import Request, ServingEngine
+    cfg = get_smoke_config("gemma3-1b")
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    prompt = np.array([5, 9, 2, 7], np.int32)
+    eng = ServingEngine(cfg, params, n_slots=2, max_len=32, eos_id=-1)
+    eng.submit(Request(0, prompt, max_new=5))
+    served = eng.run_until_done()[0].tokens_out
+    seq, want = list(prompt), []
+    for _ in range(5):
+        logits, _ = forward_prefill(params, cfg,
+                                    {"tokens": jnp.asarray([seq])})
+        want.append(int(jnp.argmax(logits[0, -1].astype(jnp.float32))))
+        seq.append(want[-1])
+    assert served == want
+
+
 def test_mesh_planner_bridge():
     from repro.core.planner import (LayoutCandidate, mesh_topology,
                                     plan_mesh_layout, score_layout)
