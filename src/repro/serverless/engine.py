@@ -29,9 +29,20 @@ read/write time, mean state distance (hops), local availability, SLO
 violations, plus simulated CPU/RAM proxies.  ``run_parallel`` drives n
 truly concurrent instances and reports fleet-level throughput, p50/p95/p99
 latency, and per-node queue depth (``repro.sim.ParallelReport``).
+
+With ``annotate=True`` the engine also opens ``jax.profiler.TraceAnnotation``
+spans, stamped by the profiler on the clock of the device planes (the
+flight recorder above runs on simulated time instead):
+``workflow.instance`` around ``run_instance``'s event loop,
+``workflow.place`` around placement and ``workflow.body.<fn>`` around each
+real function body; on the linear path of ``run_instance``, where the
+instance is its private kernel's one process so spans nest, also the
+phases ``workflow.ingress`` / ``fetch`` / ``execute`` / ``offload``.  Off,
+each site costs one attribute check and builds no annotation.
 """
 from __future__ import annotations
 
+import contextlib
 import gc
 import time as _time
 from dataclasses import dataclass, field
@@ -58,6 +69,15 @@ from repro.sim.workload import UniformStagger, iter_arrivals
 SANDBOX_INIT_S = 1.0   # Knative-class cold start per sandbox; fusion packs
                        # a whole group into one sandbox and its grouped
                        # state prefetch overlaps the init (paper §4.2)
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def _span(name: str):
+    """A host span on the profiler's clock (JAX is imported only when an
+    engine annotates)."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(name)
 
 
 @dataclass
@@ -111,7 +131,8 @@ class WorkflowEngine:
                  slo: SLO = SLO(), fusion_depth: int = 1,
                  real_compute: bool = False, seed: int = 0,
                  mode: str = "event",
-                 region_weight: float = 0.3):
+                 region_weight: float = 0.3,
+                 annotate: bool = False):
         """``strategy`` is a registered strategy name (``"databelt"`` /
         ``"random"`` / ``"stateless"`` / anything added via
         ``repro.core.strategy.register_strategy``) or an already-built
@@ -123,7 +144,9 @@ class WorkflowEngine:
         pre-event-driven engine.  ``region_weight`` scales the planner's
         region-locality term; it only takes effect on multi-region
         topologies (several cloud nodes), so single-region runs are
-        bit-identical to the pre-region engine."""
+        bit-identical to the pre-region engine.  ``annotate`` turns on the
+        profiler spans of the module docstring; they never touch
+        simulated time."""
         if mode not in MODES:
             raise ValueError(f"unknown engine mode {mode!r}; choose one "
                              f"of {MODES}")
@@ -131,6 +154,7 @@ class WorkflowEngine:
         self.slo = slo
         self.fusion_depth = max(fusion_depth, 1)
         self.real_compute = real_compute
+        self.annotate = annotate
         self.mode = mode
         # region awareness activates only when the topology actually has
         # several cloud regions
@@ -298,7 +322,10 @@ class WorkflowEngine:
                 # a documented nondeterminism opt-in (off for every
                 # golden-pinned figure)
                 w0 = _time.perf_counter()   # repro: allow(DB001): see above
-                run.payloads[fname] = fn.compute(merged) if merged else {}
+                with (_span(f"workflow.body.{fname}") if self.annotate
+                      else _NO_SPAN):
+                    run.payloads[fname] = fn.compute(merged) if merged \
+                        else {}
                 ct += _time.perf_counter() - w0  # repro: allow(DB001): see above
             m.compute_time += ct
             yield ct
@@ -490,18 +517,24 @@ class WorkflowEngine:
     # ------------------------------------------------------------------
     def _instance_proc(self, kernel: SimKernel, wf: Workflow,
                        input_bytes: float, entry: str,
-                       m: InstanceMetrics):
+                       m: InstanceMetrics, payload=None,
+                       phases: bool = False):
         """One workflow instance as a discrete-event process: a fresh
         ``StateSession`` plus the fetch/execute/offload phases per fusion
-        group, all yielding timed steps on the shared kernel."""
+        group, all yielding timed steps on the shared kernel.  ``payload``
+        is the input the first body takes (``make_payload`` when None);
+        ``phases`` opens the profiler's phase spans, which cover yields and
+        so nest only where this is its kernel's one process."""
         t0 = kernel.now
         session = StateSession(self.storage, kernel, mode=self.mode)
-        placement = self.place_functions(wf, kernel.now, entry)
+        with _span("workflow.place") if self.annotate else _NO_SPAN:
+            placement = self.place_functions(wf, kernel.now, entry)
         # linear workflows (every chain, the flood DAG's path) keep the
         # sequential pre-DAG path verbatim — same events, same sequence
         # numbers, bit-identical goldens.  Real DAGs run branches as
         # concurrent child processes joining at sync barriers.
         linear = wf.is_linear
+        phases = phases and linear
         if linear:
             gg = None
             groups = plan_fusion_groups(wf.order(), placement,
@@ -529,17 +562,19 @@ class WorkflowEngine:
 
         # the workflow input arrives at the entry node
         src_key = StateKey(wf.workflow_id, entry, "__input__")
-        if rec is not None:
-            sid = rec.begin("ingress", "phase", lane, parent=root,
-                            node=entry)
-            session.trace_parent = sid
-        yield from session.put(src_key, input_bytes, writer=entry)
-        if rec is not None:
-            rec.end(sid, bytes=input_bytes)
-        run.keys["__input__"] = src_key
-        run.sizes["__input__"] = input_bytes
-        if self.real_compute:
-            run.payloads["__input__"] = make_payload(input_bytes)
+        with _span("workflow.ingress") if phases else _NO_SPAN:
+            if rec is not None:
+                sid = rec.begin("ingress", "phase", lane, parent=root,
+                                node=entry)
+                session.trace_parent = sid
+            yield from session.put(src_key, input_bytes, writer=entry)
+            if rec is not None:
+                rec.end(sid, bytes=input_bytes)
+            run.keys["__input__"] = src_key
+            run.sizes["__input__"] = input_bytes
+            if self.real_compute:
+                run.payloads["__input__"] = make_payload(input_bytes) \
+                    if payload is None else payload
 
         if not linear:
             yield from self._dag_run(kernel, run, gg, rec, root, lane)
@@ -561,7 +596,8 @@ class WorkflowEngine:
                 sid = rec.begin("fetch", "phase", lane, parent=root,
                                 node=g.node_id, group=g.group_id)
                 session.trace_parent = sid
-            yield from self._fetch_group(kernel, run, g)
+            with _span("workflow.fetch") if phases else _NO_SPAN:
+                yield from self._fetch_group(kernel, run, g)
             if rec is not None:
                 rec.end(sid, reads=m.reads - r0,
                         hops=max(m.hops[h0:], default=0),
@@ -572,14 +608,16 @@ class WorkflowEngine:
                                 node=g.node_id, group=g.group_id,
                                 functions=len(g.function_ids))
                 session.trace_parent = sid
-            yield from self._execute_group(kernel, run, g)
+            with _span("workflow.execute") if phases else _NO_SPAN:
+                yield from self._execute_group(kernel, run, g)
             if rec is not None:
                 rec.end(sid, compute_time_s=m.compute_time - c0)
                 w0, s0 = m.write_time, m.storage_ops
                 sid = rec.begin("offload", "phase", lane, parent=root,
                                 node=g.node_id, group=g.group_id)
                 session.trace_parent = sid
-            yield from self._offload_group(kernel, run, g)
+            with _span("workflow.offload") if phases else _NO_SPAN:
+                yield from self._offload_group(kernel, run, g)
             if rec is not None:
                 rec.end(sid, write_time_s=m.write_time - w0,
                         storage_ops=m.storage_ops - s0)
@@ -606,10 +644,13 @@ class WorkflowEngine:
     # ------------------------------------------------------------------
     def run_instance(self, wf: Workflow, input_bytes: float, t0: float = 0.0,
                      entry: str = "drone0",
-                     trace=None) -> InstanceMetrics:
+                     trace=None, payload=None) -> InstanceMetrics:
         """Run ONE instance to completion on a private event loop (shared
         storage + resource queues, so sequential calls still observe each
         other's leftover backlog, as on a long-lived deployment).
+
+        ``payload`` is the input the first real body takes; when None, the
+        engine makes one of ``input_bytes`` (``make_payload``).
 
         ``trace`` attaches a flight recorder: pass ``True`` for a fresh
         ``repro.sim.trace.SpanRecorder`` or an existing recorder to
@@ -624,10 +665,12 @@ class WorkflowEngine:
             kernel.recorder = recorder
             self.storage.recorder = recorder
         m = InstanceMetrics()
-        kernel.spawn(self._instance_proc(kernel, wf, input_bytes, entry, m),
+        kernel.spawn(self._instance_proc(kernel, wf, input_bytes, entry, m,
+                                         payload, phases=self.annotate),
                      label=wf.workflow_id)
         try:
-            kernel.run()
+            with _span("workflow.instance") if self.annotate else _NO_SPAN:
+                kernel.run()
         finally:
             if recorder is not None:
                 self.storage.recorder = None
