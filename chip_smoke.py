@@ -9,9 +9,10 @@ Everything runs in this one process, which holds the chip:
    made from a seed on the chip, served by ``ServingEngine`` (4 slots,
    max_len 128) for 8 requests built as ``repro.launch.serve`` builds them.
    Every request must complete with token ids in the vocabulary, every
-   logit must be finite and the decode step must compile once.  What the
-   batched requests compute is not checked: requests of different
-   lengths share one decode position, which is wrong (ROADMAP queue 2).
+   logit must be finite, and the decode step and the prompt chunk must
+   each compile once.  What the batched requests compute is not checked:
+   requests of different lengths share one decode position, which is
+   wrong (ROADMAP queue 2).
    Then one request is served alone: the logits that chose each of its
    tokens must match one forward pass over its prompt and served tokens
    within ``LOGITS_TOL``, and each served token must be that pass's
@@ -155,8 +156,8 @@ def serve_phase(cfg: ModelConfig, dev) -> dict:
            "parameters are not on the device")
     eng = ServingEngine(cfg, params, n_slots=N_SLOTS, max_len=MAX_LEN)
 
-    # every decode call's logits are checked for finiteness on the device;
-    # lane 0's are kept for the lone request
+    # every model call's logits (decode or prompt chunk) are checked for
+    # finiteness on the device; lane 0's are kept for the lone request
     decode, finite, lane0 = eng._decode, [], []
 
     def checked_decode(*args):
@@ -179,7 +180,7 @@ def serve_phase(cfg: ModelConfig, dev) -> dict:
 
     # one request alone: every slot is free, so it is admitted to slot 0
     # and decodes at its own positions.  Its first token comes from the
-    # logits of the last prompt feed, token j from decode call P-1+j.
+    # logits of the last prompt chunk, token j from the j-th decode call.
     _check(all(s is None for s in eng.slots), "a slot is still busy")
     alone = reqs[N_REQUESTS]
     lane0.clear()
@@ -187,10 +188,10 @@ def serve_phase(cfg: ModelConfig, dev) -> dict:
     eng.run_until_done()
     _check(alone.done, "the lone request did not complete")
     P, served = len(alone.prompt), alone.tokens_out
-    K = len(served)
-    _check(len(lane0) == P + K - 1,
-           f"{len(lane0)} decode calls for {P} prompt and {K} served tokens")
-    got = np.stack([np.asarray(x, np.float32) for x in lane0[P - 1:]])
+    K, calls = len(served), -(-P // eng.chunk)
+    _check(len(lane0) == calls + K - 1,
+           f"{len(lane0)} model calls for {P} prompt and {K} served tokens")
+    got = np.stack([np.asarray(x, np.float32) for x in lane0[calls - 1:]])
     # the reference sees the prompt and the served tokens at once: its
     # logits at position P-1+j are the forward pass's next-token
     # distribution given everything served before token j
@@ -211,11 +212,15 @@ def serve_phase(cfg: ModelConfig, dev) -> dict:
     _check(bool(np.all(picked >= ref.max(axis=1) - LOGITS_TOL * scale)),
            "a served token is not the reference's greedy choice")
 
-    compiles = decode._cache_size()
+    compiles = eng._decode_step._cache_size()
     _check(compiles == 1, f"decode compiled {compiles} times")
+    chunk_compiles = eng._prefill_chunk._cache_size()
+    _check(chunk_compiles == 1, f"prompt chunk compiled {chunk_compiles} "
+           "times")
     return {"requests": len(eng.completed),
             "tokens": sum(len(r.tokens_out) for r in eng.completed),
-            "decode_calls": len(finite), "decode_compiles": compiles,
+            "model_calls": len(finite), "decode_compiles": compiles,
+            "prefill_chunks": eng.stats["prefill_chunks"],
             "alone_tokens": K,
             "alone_argmax_agree": int(np.sum(ref.argmax(axis=1) == served)),
             "logits_max_abs_diff": diff, "logits_max_abs": scale,
@@ -304,7 +309,8 @@ def main() -> int:
     stats = dev.memory_stats() or {}
     print(f"serving: arch={ARCH} (full width, {cfg.n_layers} layers) "
           f"requests={serve['requests']} tokens={serve['tokens']} "
-          f"decode_calls={serve['decode_calls']} "
+          f"model_calls={serve['model_calls']} "
+          f"prefill_chunks={serve['prefill_chunks']} "
           f"decode_compiles={serve['decode_compiles']} "
           f"peak_bytes_in_use={stats.get('peak_bytes_in_use')}", flush=True)
     print(f"serving: lone request tokens={serve['alone_tokens']} "

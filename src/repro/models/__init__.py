@@ -3,5 +3,6 @@ from repro.models.transformer import (  # noqa: F401
     forward_train,
     forward_prefill,
     forward_decode,
+    forward_chunk,
     init_cache,
 )

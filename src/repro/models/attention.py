@@ -389,6 +389,108 @@ def attention_decode(params, x, cfg: ModelConfig, kind: str, cache: dict,
     return o @ params["wo"], new_cache
 
 
+# ---------------------------------------------------------------------------
+# prefill chunk (C tokens of one lane against that lane's cache)
+# ---------------------------------------------------------------------------
+def _write_rows(buf, rows, lane, s0, last, ring: bool):
+    """Write ``rows`` (1,C,...) into lane ``lane`` of ``buf`` (B,L,...):
+    row j goes to slot (s0 + j) mod L on a ring, else to slot s0 + j (rows
+    past L are dropped); rows past ``last`` are never written.
+
+    Two read-modify-write windows of C slots, each a dynamic slice: the
+    first ends at the ring's end or starts at s0, the second starts at
+    slot 0 and takes the rows that wrapped (none off the ring)."""
+    C, L = rows.shape[1], buf.shape[1]
+    i = jnp.arange(C)
+    tail = (slice(None),) + (None,) * (rows.ndim - 2)
+    n1 = jnp.minimum(C, L - s0)                  # rows before the ring's end
+    zeros = jnp.zeros_like(rows)
+
+    def rmw(buf, start, shifted, valid):
+        idx = (lane, start) + (0,) * (rows.ndim - 2)
+        old = jax.lax.dynamic_slice(buf, idx, rows.shape)
+        new = jnp.where(valid[tail][None], shifted, old)
+        return jax.lax.dynamic_update_slice(buf, new, idx)
+
+    st = jnp.minimum(s0, L - C)
+    d = st - s0                                  # in (-C, 0]
+    shifted = jax.lax.dynamic_slice_in_dim(
+        jnp.concatenate([zeros, rows], axis=1), C + d, C, axis=1)
+    j = i + d
+    buf = rmw(buf, st, shifted, (j >= 0) & (j < n1) & (j <= last))
+    if ring:
+        shifted = jax.lax.dynamic_slice_in_dim(
+            jnp.concatenate([rows, zeros], axis=1), n1, C, axis=1)
+        j = i + n1
+        buf = rmw(buf, jnp.zeros_like(st), shifted, (j < C) & (j <= last))
+    return buf
+
+
+def attention_chunk(params, x, cfg: ModelConfig, kind: str, cache: dict,
+                    positions, last, lane):
+    """x: (1,C,D), one lane's prompt tokens at ``positions`` (C,) =
+    pos..pos+C-1; cache holds K/V (B,L,K,hd), unquantised.
+
+    Partials merged by log-sum-exp: the chunk's queries against its own
+    K/V, causal, then against the lane's cache before this chunk is
+    written (positions < pos; on a ring the absolute positions follow
+    from pos as in ``attention_decode``), C slots at a time, where a block
+    of slots that no query may see is skipped.  The window mask applies
+    to both.  Then rows 0..``last`` of the chunk's K/V go into the lane's
+    slots; a ring stays right when it wraps inside a prompt as long as
+    C <= L.  Returns (out (1,C,D), updated cache)."""
+    _, C, _ = x.shape
+    H, K, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    G = H // K
+    window = cfg.window_size if kind == ATTN_LOCAL else 0
+    theta = cfg.rope_theta if (kind == ATTN_LOCAL or not cfg.rope_theta_global) \
+        else cfg.rope_theta_global
+    scale = hd ** -0.5
+    softcap = cfg.attn_logit_softcap
+    mask_fn = make_mask_fn("causal", window)
+    pos = positions[0]
+
+    q = (x @ params["wq"]).reshape(1, C, H, hd)
+    kn = (x @ params["wk"]).reshape(1, C, K, hd)
+    vn = (x @ params["wv"]).reshape(1, C, K, hd)
+    if "q_norm" in params:
+        q = rms_norm(q, params["q_norm"], cfg.norm_eps)
+        kn = rms_norm(kn, params["k_norm"], cfg.norm_eps)
+    q = rope(q, positions[None], theta).reshape(1, C, K, G, hd)
+    kn = rope(kn, positions[None], theta)
+    o, lse = _sdpa(q, kn, vn, mask_fn(positions, positions), softcap, scale,
+                   want_lse=True)
+    o = o.astype(jnp.float32)
+
+    L = cache["k"].shape[1]
+    ring = bool(window) and L <= window
+    slot = jnp.arange(L)
+    if ring:
+        prev = pos - 1                           # the newest cached position
+        kv_pos = prev - jnp.mod(jnp.mod(prev, L) - slot, L)
+    else:
+        kv_pos = jnp.where(slot < pos, slot, -1)
+    old_k = jax.lax.dynamic_slice_in_dim(cache["k"], lane, 1, axis=0)
+    old_v = jax.lax.dynamic_slice_in_dim(cache["v"], lane, 1, axis=0)
+    for b in range(0, L, C):
+        blk = slice(b, min(b + C, L))
+        mask = mask_fn(positions, kv_pos[blk])
+
+        def merge(acc, kb=old_k[:, blk], vb=old_v[:, blk], mask=mask):
+            ob, lseb = _sdpa(q, kb, vb, mask, softcap, scale, want_lse=True)
+            return _lse_merge(*acc, ob, lseb)
+        o, lse = jax.lax.cond(mask.any(), merge, lambda acc: acc, (o, lse))
+
+    s0 = jnp.mod(pos, L) if ring else pos
+    new_cache = dict(cache)
+    new_cache["k"] = _write_rows(cache["k"], kn.astype(cache["k"].dtype),
+                                 lane, s0, last, ring)
+    new_cache["v"] = _write_rows(cache["v"], vn.astype(cache["v"].dtype),
+                                 lane, s0, last, ring)
+    o = o.astype(x.dtype).reshape(1, C, H * hd)
+    return o @ params["wo"], new_cache
+
+
 def build_ring_cache(k_full, v_full, window: int, quant: bool = False):
     """Convert full prefill K/V (B,S,K,hd) into the decode ring layout."""
     S = k_full.shape[1]

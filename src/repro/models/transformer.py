@@ -11,6 +11,7 @@ Entry points:
     forward_train(params, cfg, batch)            -> (logits, aux_loss)
     forward_prefill(params, cfg, batch)          -> (logits, cache)
     forward_decode(params, cfg, cache, tok, pos) -> (logits, cache)
+    forward_chunk(params, cfg, cache, tok, pos, last, lane) -> (logits, cache)
     init_cache(cfg, batch, seq_len)              -> cache pytree
 """
 from __future__ import annotations
@@ -357,6 +358,57 @@ def forward_decode(params, cfg: ModelConfig, cache, tokens, pos):
                               cache["tail"][j], pos, prefix_len)
         new_tail.append(nc)
     logits = _unembed(params, cfg, x)
+    return logits, {"blocks": new_block_caches, "tail": tuple(new_tail)}
+
+
+def _layer_chunk(p, x, cfg: ModelConfig, kind: str, cache, positions, last,
+                 lane):
+    """x: (1,C,D) of one lane; an attention layer with a dense MLP."""
+    h = rms_norm(x, p["ln1"], cfg.norm_eps)
+    o, new_cache = attn.attention_chunk(p["attn"], h, cfg, kind, cache,
+                                        positions, last, lane)
+    if cfg.post_norms:
+        o = rms_norm(o, p["post_ln1"], cfg.norm_eps)
+    x = x + o
+    f = mlp(p["mlp"], rms_norm(x, p["ln2"], cfg.norm_eps), cfg.act)
+    if cfg.post_norms:
+        f = rms_norm(f, p["post_ln2"], cfg.norm_eps)
+    return x + f, new_cache
+
+
+def forward_chunk(params, cfg: ModelConfig, cache, tokens, pos, last, lane):
+    """C prompt tokens of lane ``lane`` at positions pos..pos+C-1, against
+    that lane's cache, for stacks of attention layers with dense MLPs.
+
+    tokens: (1,C); pos, last, lane: int32 scalars.  Rows past ``last``
+    are padding: computed, never written to the cache.  Returns the logits
+    of row ``last`` only, (1,1,V), and the cache with the chunk's K/V in
+    the lane's slots; every other lane is left as it was."""
+    x = _embed(params, cfg, tokens)
+    positions = pos + jnp.arange(tokens.shape[1], dtype=jnp.int32)
+
+    def block_fn(carry, xs):
+        x, = carry
+        bp, bc = xs
+        new_caches = []
+        for j, kind in enumerate(cfg.layer_pattern):
+            x, nc = _layer_chunk(bp[j], x, cfg, kind, bc[j], positions, last,
+                                 lane)
+            new_caches.append(nc)
+        return (x,), tuple(new_caches)
+
+    if cfg.n_superblocks > 0:
+        (x,), new_block_caches = jax.lax.scan(
+            block_fn, (x,), (params["blocks"], cache["blocks"]))
+    else:
+        new_block_caches = ()
+    new_tail = []
+    for j, kind in enumerate(cfg.tail_pattern):
+        x, nc = _layer_chunk(params["tail"][j], x, cfg, kind,
+                             cache["tail"][j], positions, last, lane)
+        new_tail.append(nc)
+    logits = _unembed(params, cfg,
+                      jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1))
     return logits, {"blocks": new_block_caches, "tail": tuple(new_tail)}
 
 

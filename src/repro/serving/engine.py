@@ -1,18 +1,31 @@
 """Batched serving engine: continuous batching over fixed decode slots.
 
 Requests (prompt token arrays) queue at the Ingress; the engine packs them
-into ``n_slots`` decode lanes, prefilling lazily and recycling a lane as
-soon as its request finishes (EOS or max tokens) — the serving counterpart
-of the Databelt runtime: the KV-cache slot is the "function state", kept
-device-local for the lifetime of the request.
+into ``n_slots`` decode lanes, feeding each prompt as it is admitted and
+recycling a lane as soon as its request finishes (EOS or max tokens) — the
+serving counterpart of the Databelt runtime: the KV-cache slot is the
+"function state", kept device-local for the lifetime of the request.
+
+A prompt of P tokens is fed to its own lane in ceil(P/C) chunks of C
+tokens through one compiled program, ``prefill_chunk``
+(``forward_chunk``), with C = min(``PREFILL_CHUNK``, every local layer's
+ring length, ``max_len``); the other lanes are left untouched.  That takes
+a stack of global and local attention layers with dense MLPs and an
+unquantised cache, without an encoder or a prefix frontend.  Any other
+configuration (recurrent layers, whose chunk would have to scan from the
+cache's state; MoE, whose sequence routing drops tokens past capacity;
+an int8 cache; whisper; paligemma) feeds its prompt one token at a time
+through the decode program, across all lanes.  ``stats`` counts which
+way the prompt tokens went.  The first token comes from the logits of the
+prompt's last position either way.
 
 With ``annotate=True`` the engine opens ``jax.profiler.TraceAnnotation``
 spans at its layer boundaries, stamped by the profiler on the clock of the
 device planes: ``serve.step`` (one decode step, admission included),
 ``serve.admit``, ``serve.prefill`` (one per prompt) and ``serve.emit``.
 Off, each site costs one attribute check and builds no annotation.  The
-decode program is the jitted ``decode_step``, so the device trace names
-its module ``jit_decode_step``.
+device trace names the two programs' modules ``jit_decode_step`` and
+``jit_prefill_chunk``.
 """
 from __future__ import annotations
 
@@ -25,11 +38,26 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import ModelConfig
-from repro.models import forward_decode, forward_prefill, init_cache
-from repro.models.io import make_batch
+from repro.configs.base import ATTN_GLOBAL, ATTN_LOCAL, ModelConfig
+from repro.models import forward_chunk, forward_decode, init_cache
 
 _NO_SPAN = contextlib.nullcontext()
+PREFILL_CHUNK = 512      # prompt tokens a chunk: compute-bound on a v5e
+
+
+def prefill_chunk_len(cfg: ModelConfig, max_len: int) -> int:
+    """The chunk length C for ``cfg``, or 0 where prompts are fed a token
+    at a time.  C never exceeds a local layer's ring, so a chunk's slots
+    are distinct even where the ring wraps inside it."""
+    kinds = set(cfg.layer_pattern) | set(cfg.tail_pattern)
+    if not kinds <= {ATTN_GLOBAL, ATTN_LOCAL} or cfg.moe is not None \
+            or cfg.kv_quant or cfg.encoder_decoder or cfg.frontend \
+            or cfg.n_prefix_tokens:
+        return 0
+    c = min(PREFILL_CHUNK, max_len)
+    if ATTN_LOCAL in kinds:
+        c = min(c, cfg.window_size)
+    return c
 
 
 @dataclass
@@ -56,10 +84,17 @@ class ServingEngine:
         self.pos = np.zeros(n_slots, np.int32)
         self.budget = np.zeros(n_slots, np.int32)
         self.cache = init_cache(cfg, n_slots, max_len)
+        self.chunk = prefill_chunk_len(cfg, max_len)
+        self.stats = {"prefill_chunks": 0, "prompt_tokens_chunked": 0,
+                      "prompt_tokens_stepped": 0}
 
         def decode_step(p, c, tok, pos):
             return forward_decode(p, cfg, c, tok, pos)
-        self._decode = jax.jit(decode_step)
+
+        def prefill_chunk(p, c, tok, pos, last, lane):
+            return forward_chunk(p, cfg, c, tok, pos, last, lane)
+        self._decode_step = jax.jit(decode_step)
+        self._prefill_chunk = jax.jit(prefill_chunk)
         self.completed: List[Request] = []
 
     def submit(self, req: Request):
@@ -73,31 +108,56 @@ class ServingEngine:
                 if self.slots[i] is None and self.queue:
                     req = self.queue.popleft()
                     self.slots[i] = req
-                    # lazy prefill: feed prompt tokens one by one through
-                    # decode (keeps one compiled program; real TPU serving
-                    # would use a separate prefill program — see
-                    # serving/steps.py)
+                    # the prompt goes in now: in chunks to lane i alone
+                    # where the configuration allows, else a token at a
+                    # time through decode (see the module docstring)
                     self.pos[i] = 0
                     self.budget[i] = req.max_new
                     self._feed_prompt(i, req)
 
+    def _decode(self, params, cache, tokens, pos, *chunk):
+        """The engine's one entry for a model step, returning (logits,
+        cache): a (n_slots, 1) token block goes to ``decode_step``; one
+        lane's (1, C) block, with its ``last`` row and ``lane``, to
+        ``prefill_chunk``, whose logits are those of row ``last``."""
+        if chunk:
+            return self._prefill_chunk(params, cache, tokens, pos, *chunk)
+        return self._decode_step(params, cache, tokens, pos)
+
     def _feed_prompt(self, i: int, req: Request):
         with (jax.profiler.TraceAnnotation("serve.prefill") if self.annotate
               else _NO_SPAN):
-            logits = None
-            for t in req.prompt:
-                tok = jnp.full((self.n_slots, 1), int(t), jnp.int32)
-                # only slot i's lane matters; the others decode a dummy
-                # token at slot i's position, which corrupts a busy lane
-                # (one scalar position per batch: ROADMAP queue 2)
-                logits, self.cache = self._decode(
-                    self.params, self.cache, tok, np.int32(self.pos[i]))
-                self.pos[i] += 1
+            logits, P, C = None, len(req.prompt), self.chunk
+            if C:
+                # positions, last row and lane go in as strongly typed
+                # int32, so every prompt length shares one program
+                for start in range(0, P, C):
+                    part = np.asarray(req.prompt[start:start + C], np.int32)
+                    tok = np.zeros((1, C), np.int32)
+                    tok[0, :len(part)] = part
+                    logits, self.cache = self._decode(
+                        self.params, self.cache, tok, np.int32(start),
+                        np.int32(len(part) - 1), np.int32(i))
+                    self.stats["prefill_chunks"] += 1
+                self.stats["prompt_tokens_chunked"] += P
+                self.pos[i] = P
+                lane = 0
+            else:
+                for t in req.prompt:
+                    tok = jnp.full((self.n_slots, 1), int(t), jnp.int32)
+                    # only slot i's lane matters; the others decode a dummy
+                    # token at slot i's position, which corrupts a busy
+                    # lane (one scalar position per batch: ROADMAP queue 2)
+                    logits, self.cache = self._decode(
+                        self.params, self.cache, tok, np.int32(self.pos[i]))
+                    self.pos[i] += 1
+                self.stats["prompt_tokens_stepped"] += P
+                lane = i
             # the first token comes from the prompt's last logits; decode
             # steps then feed it back at position len(prompt)
             if logits is not None:
                 self._emit(i, int(jnp.argmax(
-                    logits[i, -1].astype(jnp.float32))))
+                    logits[lane, -1].astype(jnp.float32))))
 
     def _emit(self, i: int, t: int):
         """Slot i's request takes token t; the slot frees when it is done."""

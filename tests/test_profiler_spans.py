@@ -150,7 +150,8 @@ def test_dag_path_emits_no_phase_spans(net, tmp_path):
 
 def test_decode_program_is_named():
     """The device trace names a program by its jitted function: the
-    engine's decode is ``jit_decode_step``, one module per program."""
+    engine's decode is ``jit_decode_step`` and its prompt chunk
+    ``jit_prefill_chunk``, one module per program."""
     from repro.configs.base import get_smoke_config
     from repro.models import init_params
     from repro.serving.engine import ServingEngine
@@ -158,10 +159,15 @@ def test_decode_program_is_named():
     eng = ServingEngine(cfg, None, n_slots=1, max_len=16)
     params = jax.eval_shape(lambda k: init_params(k, cfg),
                             jax.random.PRNGKey(0))
-    text = eng._decode.lower(params, eng.cache,
-                             jax.ShapeDtypeStruct((1, 1), jnp.int32),
-                             np.int32(0)).as_text()
+    text = eng._decode_step.lower(params, eng.cache,
+                                  jax.ShapeDtypeStruct((1, 1), jnp.int32),
+                                  np.int32(0)).as_text()
     assert "module @jit_decode_step" in text
+    i32 = np.int32(0)
+    text = eng._prefill_chunk.lower(
+        params, eng.cache, jax.ShapeDtypeStruct((1, eng.chunk), jnp.int32),
+        i32, i32, i32).as_text()
+    assert "module @jit_prefill_chunk" in text
 
 
 def test_workflow_input_is_passed_in(net, monkeypatch):

@@ -61,8 +61,9 @@ def test_serving_engine_completes():
 
 
 def test_serving_engine_compiles_decode_once():
-    """Prompt feeding and batched decode steps pass the position with the
-    same dtype and weak type, so they share one compiled program."""
+    """Prompts of three lengths share one compiled chunk program, and the
+    decode steps one decode program: positions, last rows and lanes go in
+    with the same dtype and weak type on every call."""
     from repro.configs.base import get_smoke_config
     from repro.models import init_params
     from repro.serving.engine import Request, ServingEngine
@@ -73,7 +74,8 @@ def test_serving_engine_compiles_decode_once():
         eng.submit(Request(i, np.arange(2, 2 + n, dtype=np.int32),
                            max_new=3))
     assert len(eng.run_until_done(max_steps=100)) == 3
-    assert eng._decode._cache_size() == 1
+    assert eng._decode_step._cache_size() == 1
+    assert eng._prefill_chunk._cache_size() == 1
 
 
 def test_serving_engine_serves_greedy_continuation_of_prompt():

@@ -91,11 +91,37 @@ def test_gemma3_1b_decode_step_compiles_at_engine_shapes(one_chip):
     params = jax.tree.map(on_chip, jax.eval_shape(
         lambda k: init_params(k, cfg), jax.random.PRNGKey(0)))
     cache = jax.tree.map(on_chip, eng.cache)
-    c = eng._decode.lower(params, cache,
-                          _spec(one_chip, (4, 1), jnp.int32),
-                          _spec(one_chip, (), jnp.int32)).compile()
+    c = eng._decode_step.lower(params, cache,
+                               _spec(one_chip, (4, 1), jnp.int32),
+                               _spec(one_chip, (), jnp.int32)).compile()
     mem = c.memory_analysis()
     weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
     assert mem.argument_size_in_bytes >= weights > 1.5e9
+    assert mem.argument_size_in_bytes + mem.output_size_in_bytes \
+        + mem.temp_size_in_bytes < V5E_HBM_BYTES
+
+
+def test_danube_prefill_chunk_compiles_at_engine_shapes(one_chip):
+    """The engine's prompt chunk for h2o-danube-1.8b at full width, at the
+    benchmark's engine shapes (one slot, max_len 8192: a 512-token chunk
+    against the 4096-position ring), within one chip's memory."""
+    from repro.configs.base import get_config
+    from repro.models import init_params
+    from repro.serving.engine import ServingEngine
+
+    cfg = get_config("h2o-danube-1.8b")
+    eng = ServingEngine(cfg, None, n_slots=1, max_len=8192)
+    assert eng.chunk == 512
+    on_chip = lambda t: _spec(one_chip, t.shape, t.dtype)
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda k: init_params(k, cfg), jax.random.PRNGKey(0)))
+    cache = jax.tree.map(on_chip, eng.cache)
+    i32 = _spec(one_chip, (), jnp.int32)
+    c = eng._prefill_chunk.lower(
+        params, cache, _spec(one_chip, (1, eng.chunk), jnp.int32),
+        i32, i32, i32).compile()
+    mem = c.memory_analysis()
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    assert mem.argument_size_in_bytes >= weights > 3.5e9
     assert mem.argument_size_in_bytes + mem.output_size_in_bytes \
         + mem.temp_size_in_bytes < V5E_HBM_BYTES
