@@ -25,13 +25,33 @@ RWKV = "rwkv"              # RWKV-6 time-mix block
 
 @dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int
+    n_experts: int                    # the router's width, as published
     experts_per_token: int
     d_ff_expert: int
     dense_residual: bool = False      # arctic: parallel dense FFN
     d_ff_dense: int = 0               # width of the dense residual FFN
     capacity_factor: float = 1.25
     router_jitter: float = 0.0
+    n_held: int = 0                   # experts whose weights are here; 0 = all
+    first_held: int = 0               # router id of the first held expert
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (arXiv 2309.00071) in the form of HF
+    ``rope_type: yarn``: frequencies blended between theta's and theta's
+    over ``factor`` by a ramp between the dimensions that turn
+    ``beta_fast`` and ``beta_slow`` times over the original context; cos
+    and sin scaled by ``attention_factor``."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
 
 
 @dataclass(frozen=True)
@@ -53,6 +73,7 @@ class ModelConfig:
     final_logit_softcap: float = 0.0
     rope_theta: float = 10_000.0
     rope_theta_global: float = 0.0    # gemma3: different base on global layers
+    rope_yarn: Optional[YarnConfig] = None  # YaRN on global layers
     qk_norm: bool = False             # qwen3-style per-head RMSNorm on q/k
 
     # --- block details ---
@@ -89,6 +110,12 @@ class ModelConfig:
     attn_causal_pack: str = "auto"    # on | off | auto (auto = heads%tp==0)
     scan_reps_cap: int = 0            # 0 = scan all superblocks (calibration
                                       # configs cap this to force a tail)
+
+    def __post_init__(self):
+        # a configuration file gives the nested groups as plain dicts
+        for name, kind in (("moe", MoEConfig), ("rope_yarn", YarnConfig)):
+            if isinstance(getattr(self, name), dict):
+                object.__setattr__(self, name, kind(**getattr(self, name)))
 
     # ----- derived layout helpers -----
     @property
@@ -138,7 +165,7 @@ class ModelConfig:
                 n += attn
                 if self.moe is not None:
                     e = self.moe.experts_per_token if active_only \
-                        else self.moe.n_experts
+                        else self.moe.held
                     n += e * 3 * d * self.moe.d_ff_expert + d * self.moe.n_experts
                     if self.moe.dense_residual:
                         n += 3 * d * (self.moe.d_ff_dense or self.d_ff)
@@ -223,9 +250,12 @@ def get_smoke_config(name: str) -> ModelConfig:
     pat = cfg.pattern_len
     moe = cfg.moe
     if moe is not None:
+        # 8 experts, of which the same share is held from the same place
+        share = lambda n: n * 8 // moe.n_experts
         moe = dataclasses.replace(
             moe, n_experts=8, experts_per_token=min(moe.experts_per_token, 2),
-            d_ff_expert=64, d_ff_dense=64 if moe.dense_residual else 0)
+            d_ff_expert=64, d_ff_dense=64 if moe.dense_residual else 0,
+            n_held=share(moe.n_held), first_held=share(moe.first_held))
     return dataclasses.replace(
         cfg,
         n_layers=2 * pat,
@@ -255,7 +285,7 @@ def list_archs() -> list:
 _ARCH_MODULES = [
     "paligemma_3b", "whisper_small", "gemma3_1b", "gemma2_9b",
     "h2o_danube_1_8b", "internlm2_20b", "qwen3_moe_235b_a22b",
-    "arctic_480b", "recurrentgemma_2b", "rwkv6_7b",
+    "arctic_480b", "recurrentgemma_2b", "rwkv6_7b", "mellum2_12b_a2_5b",
 ]
 
 

@@ -5,4 +5,5 @@ from repro.models.transformer import (  # noqa: F401
     forward_decode,
     forward_chunk,
     init_cache,
+    expert_counts,
 )

@@ -48,6 +48,16 @@ def init_attention(key, cfg: ModelConfig, cross: bool = False) -> dict:
     return p
 
 
+def _rope(x, positions, cfg: ModelConfig, kind: str):
+    """Rotary embedding of a layer: local layers at ``rope_theta``; global
+    ones at ``rope_theta_global`` where set, with YaRN where
+    ``rope_yarn`` is set."""
+    if kind == ATTN_LOCAL:
+        return rope(x, positions, cfg.rope_theta)
+    return rope(x, positions, cfg.rope_theta_global or cfg.rope_theta,
+                cfg.rope_yarn)
+
+
 # ---------------------------------------------------------------------------
 # masking helpers: mask(q_pos, kv_pos) -> bool allow
 # ---------------------------------------------------------------------------
@@ -179,8 +189,6 @@ def attention_seq(params, x, cfg: ModelConfig, kind: str, positions,
     H, K, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     G = H // K
     window = cfg.window_size if kind == ATTN_LOCAL else 0
-    theta = cfg.rope_theta if (kind == ATTN_LOCAL or not cfg.rope_theta_global) \
-        else cfg.rope_theta_global
 
     q = (x @ params["wq"]).reshape(B, S, H, hd)
     if kv_override is None:
@@ -196,8 +204,8 @@ def attention_seq(params, x, cfg: ModelConfig, kind: str, positions,
         k = rms_norm(k, params["k_norm"], cfg.norm_eps)
     use_rope = not cfg.encoder_decoder
     if use_rope:
-        q = rope(q, positions, theta)
-        k = rope(k, kv_pos, theta)
+        q = _rope(q, positions, cfg, kind)
+        k = _rope(k, kv_pos, cfg, kind)
     q = constrain(q, ("batch", "seq", "heads", None))
     k = constrain(k, ("batch", "seq", "kv_heads", None))
     v = constrain(v, ("batch", "seq", "kv_heads", None))
@@ -321,8 +329,6 @@ def attention_decode(params, x, cfg: ModelConfig, kind: str, cache: dict,
     B, _, D = x.shape
     H, K, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     G = H // K
-    theta = cfg.rope_theta if (kind == ATTN_LOCAL or not cfg.rope_theta_global) \
-        else cfg.rope_theta_global
     scale = hd ** -0.5
 
     q = (x @ params["wq"]).reshape(B, 1, H, hd)
@@ -345,8 +351,8 @@ def attention_decode(params, x, cfg: ModelConfig, kind: str, cache: dict,
         kn = rms_norm(kn, params["k_norm"], cfg.norm_eps)
     posv = jnp.full((1,), pos, jnp.int32)
     if use_rope:
-        q = rope(q, posv[None, :], theta)
-        kn = rope(kn, posv[None, :], theta)
+        q = _rope(q, posv[None, :], cfg, kind)
+        kn = _rope(kn, posv[None, :], cfg, kind)
     quant = "ksc" in cache
     if quant:
         kn_q, kn_s = _kv_quantize(kn)
@@ -443,8 +449,6 @@ def attention_chunk(params, x, cfg: ModelConfig, kind: str, cache: dict,
     H, K, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     G = H // K
     window = cfg.window_size if kind == ATTN_LOCAL else 0
-    theta = cfg.rope_theta if (kind == ATTN_LOCAL or not cfg.rope_theta_global) \
-        else cfg.rope_theta_global
     scale = hd ** -0.5
     softcap = cfg.attn_logit_softcap
     mask_fn = make_mask_fn("causal", window)
@@ -456,8 +460,8 @@ def attention_chunk(params, x, cfg: ModelConfig, kind: str, cache: dict,
     if "q_norm" in params:
         q = rms_norm(q, params["q_norm"], cfg.norm_eps)
         kn = rms_norm(kn, params["k_norm"], cfg.norm_eps)
-    q = rope(q, positions[None], theta).reshape(1, C, K, G, hd)
-    kn = rope(kn, positions[None], theta)
+    q = _rope(q, positions[None], cfg, kind).reshape(1, C, K, G, hd)
+    kn = _rope(kn, positions[None], cfg, kind)
     o, lse = _sdpa(q, kn, vn, mask_fn(positions, positions), softcap, scale,
                    want_lse=True)
     o = o.astype(jnp.float32)

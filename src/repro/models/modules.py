@@ -1,6 +1,8 @@
 """Shared neural-net building blocks (pure JAX, pytree params)."""
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -38,14 +40,40 @@ def group_norm_heads(x, scale, bias, n_heads: int, eps: float = 1e-5):
 # ---------------------------------------------------------------------------
 # rotary embeddings
 # ---------------------------------------------------------------------------
-def rope(x, positions, theta: float):
+def rope_freqs(hd: int, theta: float, yarn=None):
+    """(inverse frequencies (hd/2,), scale of cos and sin) of a rotary
+    table; with ``yarn`` (a ``YarnConfig``) the YaRN blend of HF's
+    ``rope_type: yarn``: dimension i keeps theta's frequency below the
+    ramp that runs from ``low`` to ``high``, takes it over ``factor``
+    above, and a blend between."""
+    half = hd // 2
+    freq = theta ** (-np.arange(0, half, dtype=np.float32) / half)
+    if yarn is None:
+        return freq, 1.0
+
+    def dim(turns):      # the dimension that turns ``turns`` times
+        return hd * math.log(yarn.original_max_position_embeddings
+                             / (turns * 2 * math.pi)) / (2 * math.log(theta))
+    low = max(math.floor(dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(dim(yarn.beta_slow)), hd - 1)
+    if high == low:
+        high += 1e-3
+    m = 1 - np.clip((np.arange(half, dtype=np.float32) - low)
+                    / (high - low), 0, 1)   # 1 keeps theta's frequency
+    freq = freq / yarn.factor * (1 - m) + freq * m
+    return freq.astype(np.float32), yarn.attention_factor
+
+
+def rope(x, positions, theta: float, yarn=None):
     """x: (..., S, H, hd); positions: (..., S) int32."""
     hd = x.shape[-1]
     half = hd // 2
-    freq = theta ** (-np.arange(0, half, dtype=np.float32) / half)
+    freq, scale = rope_freqs(hd, theta, yarn)
     ang = positions[..., None].astype(jnp.float32) * freq          # (..., S, half)
     ang = ang[..., None, :]                                        # (..., S, 1, half)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if yarn is not None:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

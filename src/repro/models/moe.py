@@ -1,9 +1,13 @@
-"""Top-k mixture-of-experts with expert parallelism over the ``model`` axis.
+"""Top-k mixture-of-experts: the held share on one device, and expert
+parallelism over the ``model`` axis.
 
-Two execution paths with identical semantics:
-
-* ``moe_dense`` — one-hot dispatch einsum oracle.  O(T*E*C) memory; used for
-  smoke tests and as the numerical reference for the sharded path.
+* ``moe_held`` — what one device runs (``moe_layer`` without a mesh): a
+  dropless layer over the experts whose weights are here
+  (``MoEConfig.n_held`` from ``first_held``).  It routes over all
+  ``n_experts`` and computes every token's held choices, none dropped;
+  what the experts held elsewhere would add is left out.
+* ``moe_dense`` — one-hot-free capacity oracle with GShard dropping;
+  the numerical reference that ``moe_sharded``'s parity tests call.
 * ``moe_sharded`` — sort-based dispatch inside ``shard_map``.  Experts are
   sharded over the ``model`` mesh axis ("EP-as-TP"): tokens stay sharded over
   the data axes and replicated over ``model``; every model-rank routes all its
@@ -11,10 +15,8 @@ Two execution paths with identical semantics:
   collective cost therefore equals a dense Megatron FFN (one psum), with no
   extra all-to-all on the critical path — this is the "state fusion" story of
   the paper applied to expert state: per-expert fetches are fused into the one
-  boundary collective that TP already pays for.
-
-Capacity-dropped tokens fall back to the identity (residual) path, standard
-GShard behaviour.
+  boundary collective that TP already pays for.  Capacity-dropped tokens
+  fall back to the identity (residual) path, as in ``moe_dense``.
 """
 from __future__ import annotations
 
@@ -27,16 +29,18 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.distributed import context as dctx
-from repro.models.modules import activation, pdtype
+from repro.models.modules import activation, mlp, pdtype
 
 
 def init_moe(key, cfg: ModelConfig) -> dict:
+    """The router over all ``n_experts``; the weights of the held ones."""
     m = cfg.moe
-    d, e, f = cfg.d_model, m.n_experts, m.d_ff_expert
+    d, e, f = cfg.d_model, m.held, m.d_ff_expert
     dt = pdtype(cfg)
     ks = jax.random.split(key, 7)
     p = {
-        "router": jax.random.normal(ks[0], (d, e), jnp.float32) * d ** -0.5,
+        "router": jax.random.normal(ks[0], (d, m.n_experts), jnp.float32)
+        * d ** -0.5,
         "w_gate": jax.random.normal(ks[1], (e, d, f), dt) * d ** -0.5,
         "w_up": jax.random.normal(ks[2], (e, d, f), dt) * d ** -0.5,
         "w_down": jax.random.normal(ks[3], (e, f, d), dt) * f ** -0.5,
@@ -262,7 +266,63 @@ def moe_sharded(params, x, cfg: ModelConfig, decode: bool = False):
     return y, aux
 
 
-def moe_layer(params, x, cfg: ModelConfig, decode: bool = False):
+# ---------------------------------------------------------------------------
+# the held share (one device): dropless
+# ---------------------------------------------------------------------------
+def _held_choices(idx, cfg: ModelConfig, valid):
+    """(T, k, held) one-hot of each (token, choice) pair's held expert:
+    a zero row where the expert is held elsewhere or the token is not
+    ``valid``."""
+    m = cfg.moe
+    sel = jax.nn.one_hot(idx - m.first_held, m.held, dtype=jnp.float32)
+    if valid is not None:
+        sel = sel * valid.reshape(-1, 1, 1)
+    return sel
+
+
+def held_rows(params, x, cfg: ModelConfig, valid=None):
+    """(pairs, routed) of ``moe_held`` on ``x``: the (token, held expert)
+    pairs that carry a weight, those of ``valid`` tokens whose top k take
+    a held expert, and the ``valid`` tokens times k.  Same routing as the
+    layer's, so XLA computes it once."""
+    m = cfg.moe
+    xf = x.reshape(-1, x.shape[-1])
+    _, idx, _ = _route(xf, params["router"], m.experts_per_token)
+    rows = jnp.sum(_held_choices(idx, cfg, valid)).astype(jnp.int32)
+    n = xf.shape[0] if valid is None else jnp.sum(valid, dtype=jnp.int32)
+    return rows, jnp.asarray(n * m.experts_per_token, jnp.int32)
+
+
+def moe_held(params, x, cfg: ModelConfig, valid=None):
+    """The part of the MoE layer that the held experts give, dropless.
+
+    Softmax over the router's ``n_experts``, top-k, the k weights
+    renormalised; every token's held choices are computed, none dropped.
+    Each token goes through every held expert and each result is
+    weighted by the token's gate on that expert, which is 0 where the
+    expert is not among its top k or the token is not ``valid`` (B,S)
+    (default: all).  One form for every shape: on a TPU v5e it beat
+    ``ragged_dot`` over the pairs sorted by expert both at one decode
+    token and at a 512-row chunk (PERF.md, section 6)."""
+    m = cfg.moe
+    B, S, D = x.shape
+    xf = x.reshape(B * S, D)
+    gate, idx, probs = _route(xf, params["router"], m.experts_per_token)
+    g = jnp.einsum("tk,tke->te", gate, _held_choices(idx, cfg, valid))
+    act = activation(cfg.act)
+    h = act(jnp.einsum("td,edf->tef", xf, params["w_gate"])) \
+        * jnp.einsum("td,edf->tef", xf, params["w_up"])
+    h = (h.astype(jnp.float32) * g[:, :, None]).astype(x.dtype)
+    y = jnp.einsum("tef,efd->td", h, params["w_down"]).reshape(B, S, D)
+    if "dense" in params:
+        y = y + mlp(params["dense"], x, cfg.act)
+    return y, _aux_loss(probs, idx, m.n_experts)
+
+
+def moe_layer(params, x, cfg: ModelConfig, decode: bool = False,
+              valid=None):
+    """The expert layer: sharded over a mesh where one is set, else the
+    held share on this device (``moe_held``), for every caller."""
     if dctx.current() is not None:
         return moe_sharded(params, x, cfg, decode=decode)
-    return moe_dense(params, x, cfg)
+    return moe_held(params, x, cfg, valid)

@@ -13,6 +13,7 @@ Entry points:
     forward_decode(params, cfg, cache, tok, pos) -> (logits, cache)
     forward_chunk(params, cfg, cache, tok, pos, last, lane) -> (logits, cache)
     init_cache(cfg, batch, seq_len)              -> cache pytree
+    expert_counts(cache)                         -> {counter: int}
 """
 from __future__ import annotations
 
@@ -21,6 +22,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ATTN_GLOBAL, ATTN_LOCAL, RGLRU, RWKV, ModelConfig
 from repro.distributed.context import constrain
@@ -184,6 +186,9 @@ def _layer_decode(p, x, cfg: ModelConfig, kind: str, cache, pos, prefix_len):
         h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
         if "moe" in p:
             f, _ = moe_mod.moe_layer(p["moe"], h2, cfg, decode=True)
+            if "experts" in cache:
+                new_cache["experts"] = _count_experts(
+                    cache["experts"], 1, *moe_mod.held_rows(p["moe"], h2, cfg))
         else:
             f = mlp(p["mlp"], h2, cfg.act)
         if cfg.post_norms:
@@ -363,14 +368,24 @@ def forward_decode(params, cfg: ModelConfig, cache, tokens, pos):
 
 def _layer_chunk(p, x, cfg: ModelConfig, kind: str, cache, positions, last,
                  lane):
-    """x: (1,C,D) of one lane; an attention layer with a dense MLP."""
+    """x: (1,C,D) of one lane; an attention layer with a dense MLP or
+    experts, whose gates are 0 on the rows past ``last``."""
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
     o, new_cache = attn.attention_chunk(p["attn"], h, cfg, kind, cache,
                                         positions, last, lane)
     if cfg.post_norms:
         o = rms_norm(o, p["post_ln1"], cfg.norm_eps)
     x = x + o
-    f = mlp(p["mlp"], rms_norm(x, p["ln2"], cfg.norm_eps), cfg.act)
+    h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
+    if "moe" in p:
+        valid = (jnp.arange(x.shape[1]) <= last)[None]
+        f, _ = moe_mod.moe_layer(p["moe"], h2, cfg, valid=valid)
+        if "experts" in cache:
+            new_cache["experts"] = _count_experts(
+                cache["experts"], 0,
+                *moe_mod.held_rows(p["moe"], h2, cfg, valid))
+    else:
+        f = mlp(p["mlp"], h2, cfg.act)
     if cfg.post_norms:
         f = rms_norm(f, p["post_ln2"], cfg.norm_eps)
     return x + f, new_cache
@@ -378,7 +393,8 @@ def _layer_chunk(p, x, cfg: ModelConfig, kind: str, cache, positions, last,
 
 def forward_chunk(params, cfg: ModelConfig, cache, tokens, pos, last, lane):
     """C prompt tokens of lane ``lane`` at positions pos..pos+C-1, against
-    that lane's cache, for stacks of attention layers with dense MLPs.
+    that lane's cache, for stacks of attention layers with dense MLPs or
+    experts.
 
     tokens: (1,C); pos, last, lane: int32 scalars.  Rows past ``last``
     are padding: computed, never written to the cache.  Returns the logits
@@ -419,6 +435,39 @@ def _sin_at(pos, d, dtype):
 
 
 # ---------------------------------------------------------------------------
+# expert counters: in an MoE layer's cache entry from ``init_cache``, rows
+# of 64-bit counts held as (low, high) uint32 words, added to inside the
+# chunk and decode programs
+# ---------------------------------------------------------------------------
+EXPERT_COUNTS = ("expert_rows_prefill", "expert_rows_decode",
+                 "expert_pairs_routed")
+
+
+def _count_experts(counts, row: int, rows, routed):
+    """Add ``rows`` (token, held expert) pairs computed to counter ``row``
+    and ``routed`` pairs to ``expert_pairs_routed``."""
+    add = jnp.zeros((len(EXPERT_COUNTS),), jnp.uint32)
+    add = add.at[row].set(rows.astype(jnp.uint32))
+    add = add.at[-1].set(routed.astype(jnp.uint32))
+    lo = counts[:, 0] + add
+    return jnp.stack([lo, counts[:, 1] + (lo < add).astype(jnp.uint32)], 1)
+
+
+def expert_counts(cache) -> dict:
+    """The expert counters of ``cache`` summed over its layers, as ints
+    (one device-to-host copy); empty where the cache has none."""
+    leaves = [c["experts"] for c in (*cache["blocks"], *cache["tail"])
+              if "experts" in c]
+    if not leaves:
+        return {}
+    words = np.concatenate([np.asarray(t).reshape(-1, len(EXPERT_COUNTS), 2)
+                            for t in jax.device_get(leaves)])
+    words = words.astype(np.uint64)
+    total = (words[..., 0] + (words[..., 1] << np.uint64(32))).sum(0)
+    return {k: int(v) for k, v in zip(EXPERT_COUNTS, total)}
+
+
+# ---------------------------------------------------------------------------
 # cache init (zeros; decode-from-scratch or dry-run stand-in)
 # ---------------------------------------------------------------------------
 def _layer_cache(cfg: ModelConfig, kind: str, batch: int, seq_len: int,
@@ -428,6 +477,8 @@ def _layer_cache(cfg: ModelConfig, kind: str, batch: int, seq_len: int,
         if cfg.encoder_decoder:
             c.update(attn.init_attn_cache(cfg, kind, batch, seq_len, dtype,
                                           cross_len=cross_len))
+        if cfg.moe is not None:
+            c["experts"] = jnp.zeros((len(EXPERT_COUNTS), 2), jnp.uint32)
         return c
     if kind == RGLRU:
         return rglru_mod.init_rglru_cache(cfg, batch, dtype)

@@ -10,14 +10,21 @@ A prompt of P tokens is fed to its own lane in ceil(P/C) chunks of C
 tokens through one compiled program, ``prefill_chunk``
 (``forward_chunk``), with C = min(``PREFILL_CHUNK``, every local layer's
 ring length, ``max_len``); the other lanes are left untouched.  That takes
-a stack of global and local attention layers with dense MLPs and an
-unquantised cache, without an encoder or a prefix frontend.  Any other
-configuration (recurrent layers, whose chunk would have to scan from the
-cache's state; MoE, whose sequence routing drops tokens past capacity;
-an int8 cache; whisper; paligemma) feeds its prompt one token at a time
-through the decode program, across all lanes.  ``stats`` counts which
-way the prompt tokens went.  The first token comes from the logits of the
-prompt's last position either way.
+a stack of global and local attention layers, with dense MLPs or experts
+(the dropless held share of ``models/moe.py``, whose gates are 0 on a
+chunk's padding rows), and an unquantised cache, without an encoder or a
+prefix frontend.  Any other configuration (recurrent layers, whose
+chunk would have to scan from the cache's state; an int8 cache; whisper;
+paligemma) feeds its prompt one token at a time through the decode
+program, across all lanes.  ``stats`` counts which way the prompt tokens
+went, and for MoE configurations the (token, held expert) pairs that
+carry a weight (``expert_rows_prefill``, ``expert_rows_decode``, over
+every layer, a chunk's padding excluded, a decode step's every lane
+included) and the pairs routed (``expert_pairs_routed``, tokens times k
+times layers).  Those
+are added to on the device, inside the two programs, and copied to the
+host when ``stats`` is read.  The first token comes from the logits of
+the prompt's last position either way.
 
 With ``annotate=True`` the engine opens ``jax.profiler.TraceAnnotation``
 spans at its layer boundaries, stamped by the profiler on the clock of the
@@ -39,7 +46,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ATTN_GLOBAL, ATTN_LOCAL, ModelConfig
-from repro.models import forward_chunk, forward_decode, init_cache
+from repro.models import (expert_counts, forward_chunk, forward_decode,
+                          init_cache)
+from repro.models.modules import pdtype
 
 _NO_SPAN = contextlib.nullcontext()
 PREFILL_CHUNK = 512      # prompt tokens a chunk: compute-bound on a v5e
@@ -50,9 +59,8 @@ def prefill_chunk_len(cfg: ModelConfig, max_len: int) -> int:
     at a time.  C never exceeds a local layer's ring, so a chunk's slots
     are distinct even where the ring wraps inside it."""
     kinds = set(cfg.layer_pattern) | set(cfg.tail_pattern)
-    if not kinds <= {ATTN_GLOBAL, ATTN_LOCAL} or cfg.moe is not None \
-            or cfg.kv_quant or cfg.encoder_decoder or cfg.frontend \
-            or cfg.n_prefix_tokens:
+    if not kinds <= {ATTN_GLOBAL, ATTN_LOCAL} or cfg.kv_quant \
+            or cfg.encoder_decoder or cfg.frontend or cfg.n_prefix_tokens:
         return 0
     c = min(PREFILL_CHUNK, max_len)
     if ATTN_LOCAL in kinds:
@@ -83,10 +91,10 @@ class ServingEngine:
         self.slots: List[Optional[Request]] = [None] * n_slots
         self.pos = np.zeros(n_slots, np.int32)
         self.budget = np.zeros(n_slots, np.int32)
-        self.cache = init_cache(cfg, n_slots, max_len)
+        self.cache = init_cache(cfg, n_slots, max_len, pdtype(cfg))
         self.chunk = prefill_chunk_len(cfg, max_len)
-        self.stats = {"prefill_chunks": 0, "prompt_tokens_chunked": 0,
-                      "prompt_tokens_stepped": 0}
+        self._counts = {"prefill_chunks": 0, "prompt_tokens_chunked": 0,
+                        "prompt_tokens_stepped": 0}
 
         def decode_step(p, c, tok, pos):
             return forward_decode(p, cfg, c, tok, pos)
@@ -96,6 +104,12 @@ class ServingEngine:
         self._decode_step = jax.jit(decode_step)
         self._prefill_chunk = jax.jit(prefill_chunk)
         self.completed: List[Request] = []
+
+    @property
+    def stats(self) -> Dict[str, int]:
+        """The host's prompt counters, and the device's expert counters
+        (fetched now) for an MoE configuration."""
+        return {**self._counts, **expert_counts(self.cache)}
 
     def submit(self, req: Request):
         self.queue.append(req)
@@ -138,8 +152,8 @@ class ServingEngine:
                     logits, self.cache = self._decode(
                         self.params, self.cache, tok, np.int32(start),
                         np.int32(len(part) - 1), np.int32(i))
-                    self.stats["prefill_chunks"] += 1
-                self.stats["prompt_tokens_chunked"] += P
+                    self._counts["prefill_chunks"] += 1
+                self._counts["prompt_tokens_chunked"] += P
                 self.pos[i] = P
                 lane = 0
             else:
@@ -151,7 +165,7 @@ class ServingEngine:
                     logits, self.cache = self._decode(
                         self.params, self.cache, tok, np.int32(self.pos[i]))
                     self.pos[i] += 1
-                self.stats["prompt_tokens_stepped"] += P
+                self._counts["prompt_tokens_stepped"] += P
                 lane = i
             # the first token comes from the prompt's last logits; decode
             # steps then feed it back at position len(prompt)

@@ -16,6 +16,7 @@ EXPECTED = {
     "arctic-480b": (35, 7168, 56, 8, 4864, 32_000),
     "recurrentgemma-2b": (26, 2560, 10, 1, 7680, 256_000),
     "rwkv6-7b": (32, 4096, 64, 64, 14336, 65_536),
+    "mellum2-12b-a2.5b": (28, 2304, 32, 4, 7168, 98_304),
 }
 
 PARAM_RANGES = {  # total params (billions), generous bounds
@@ -29,6 +30,7 @@ PARAM_RANGES = {  # total params (billions), generous bounds
     "arctic-480b": (400.0, 540.0),
     "recurrentgemma-2b": (1.8, 3.4),
     "rwkv6-7b": (5.0, 9.0),
+    "mellum2-12b-a2.5b": (11.0, 13.5),
 }
 
 

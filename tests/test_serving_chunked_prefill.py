@@ -1,7 +1,7 @@
 """``ServingEngine`` feeds a prompt in chunks of C tokens to its own lane
 through one compiled ``prefill_chunk`` program, where the configuration is
-a stack of attention layers; other configurations feed it a token at a
-time through decode."""
+a stack of attention layers (with dense MLPs or experts); other
+configurations feed it a token at a time through decode."""
 import dataclasses
 
 import jax
@@ -148,10 +148,11 @@ def test_recurrent_config_feeds_a_token_at_a_time():
     assert eng._prefill_chunk._cache_size() == 0
 
 
-# attention stacks with dense MLPs take chunks; recurrent layers, MoE,
-# an encoder (whisper) or an image prefix (paligemma) do not
+# attention stacks with dense MLPs or experts take chunks; recurrent
+# layers, an encoder (whisper) or an image prefix (paligemma) do not
 CHUNKED = {"gemma2-9b": 32, "gemma3-1b": 32, "h2o-danube-1.8b": 32,
-           "internlm2-20b": MAX_LEN}
+           "internlm2-20b": MAX_LEN, "qwen3-moe-235b-a22b": MAX_LEN,
+           "arctic-480b": MAX_LEN, "mellum2-12b-a2.5b": 32}
 
 
 @pytest.mark.parametrize("arch", list_archs())

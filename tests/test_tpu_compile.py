@@ -125,3 +125,41 @@ def test_danube_prefill_chunk_compiles_at_engine_shapes(one_chip):
     assert mem.argument_size_in_bytes >= weights > 3.5e9
     assert mem.argument_size_in_bytes + mem.output_size_in_bytes \
         + mem.temp_size_in_bytes < V5E_HBM_BYTES
+
+
+def test_mellum_share_programs_compile_at_engine_shapes(one_chip):
+    """The benchmark's ``mellum2-12b-a2.5b-ep2`` (20 layers, 32 of 64
+    experts held, max_len 4096, one slot): the prompt chunk and the decode
+    step compile for a v5e, each within one chip's memory beside its
+    9.69 GB of weights."""
+    import json
+    import sys
+    from pathlib import Path
+
+    from repro.models import init_params
+    from repro.serving.engine import ServingEngine
+
+    root = Path(__file__).resolve().parents[1]
+    if str(root) not in sys.path:
+        sys.path.insert(0, str(root))
+    from perfbench import common
+    c = json.loads((common.BENCH_DIR / "configs" /
+                    "mellum2-12b-a2.5b-ep2.json").read_text())
+    cfg = common.load_module("runners", "serve").model_config(c)
+    eng = ServingEngine(cfg, None, n_slots=1, max_len=c["max_len"])
+    assert eng.chunk == 512
+    on_chip = lambda t: _spec(one_chip, t.shape, t.dtype)
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda k: init_params(k, cfg), jax.random.PRNGKey(0)))
+    cache = jax.tree.map(on_chip, eng.cache)
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    assert 9.6e9 < weights < 9.8e9
+    i32 = _spec(one_chip, (), jnp.int32)
+    for program, args in (
+            (eng._prefill_chunk, (_spec(one_chip, (1, 512), jnp.int32),
+                                  i32, i32, i32)),
+            (eng._decode_step, (_spec(one_chip, (1, 1), jnp.int32), i32))):
+        mem = program.lower(params, cache, *args).compile().memory_analysis()
+        assert mem.argument_size_in_bytes >= weights
+        assert mem.argument_size_in_bytes + mem.output_size_in_bytes \
+            + mem.temp_size_in_bytes < V5E_HBM_BYTES
