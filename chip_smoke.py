@@ -50,6 +50,9 @@ from repro.kernels.flash_attention.kernel import (  # noqa: E402
 from repro.kernels.flash_attention.ref import (  # noqa: E402
     flash_attention_ref)
 from repro.kernels.rglru_scan.kernel import rglru_scan_blocked  # noqa: E402
+from repro.kernels.routed_experts.ops import routed_experts  # noqa: E402
+from repro.kernels.routed_experts.ref import (  # noqa: E402
+    routed_experts_ref)
 from repro.kernels.rglru_scan.ref import rglru_scan_ref  # noqa: E402
 from repro.kernels.rwkv6_chunk.kernel import wkv6_chunked  # noqa: E402
 from repro.kernels.rwkv6_chunk.ref import wkv6_ref  # noqa: E402
@@ -114,6 +117,23 @@ def _wkv6(key, BH, S, hd):
             lambda: wkv6_ref(r, k, v, logw, u))
 
 
+def _routed(key, L, E, T, k, D, F):
+    """bf16 experts stacked over L layers; the last layer's, T tokens'
+    top-k choices, some held elsewhere (index E)."""
+    ks = jax.random.split(key, 6)
+    x = jax.random.normal(ks[0], (T, D), jnp.bfloat16)
+    w = [(jax.random.normal(kk, (L, E) + s, jnp.bfloat16)
+          * jnp.bfloat16(s[0] ** -0.5))
+         for kk, s in zip(ks[1:4], ((D, F), (D, F), (F, D)))]
+    expert = jax.random.randint(ks[4], (T, k), 0, E + E // 4)
+    gate = jax.nn.softmax(jax.random.normal(ks[5], (T, k)), -1)
+    act = jax.nn.silu
+    return (lambda: routed_experts(x, gate, expert, *w, act=act,
+                                   layer=jnp.int32(L - 1)),
+            lambda: routed_experts_ref(x, gate, expert,
+                                       *(v[L - 1] for v in w), act=act))
+
+
 # name -> (inputs and calls, real-width arguments, bound)
 KERNEL_CASES = {
     # gemma3-1b: 4 query heads over 1 kv head, head_dim 256, window 512
@@ -125,6 +145,9 @@ KERNEL_CASES = {
     "rglru_scan": (_rglru, dict(B=2, S=1024, C=2560), SCAN_TOL),
     # rwkv6-7b: 64 heads of 64, one sequence
     "wkv6": (_wkv6, dict(BH=64, S=512, hd=64), DOT_TOL),
+    # Mellum 2's held share at decode: 32 experts of 896, top 8, one token
+    "routed_experts": (_routed, dict(L=2, E=32, T=1, k=8, D=2304, F=896),
+                       DOT_TOL),
 }
 
 

@@ -5,7 +5,11 @@ parallelism over the ``model`` axis.
   dropless layer over the experts whose weights are here
   (``MoEConfig.n_held`` from ``first_held``).  It routes over all
   ``n_experts`` and computes every token's held choices, none dropped;
-  what the experts held elsewhere would add is left out.
+  what the experts held elsewhere would add is left out.  Where the
+  rows' (token, choice) pairs cannot reach every held expert (a decode
+  step) it reads only the routed experts' weights, through the
+  ``routed_experts`` kernel; otherwise every row goes through every held
+  expert.
 * ``moe_dense`` — one-hot-free capacity oracle with GShard dropping;
   the numerical reference that ``moe_sharded``'s parity tests call.
 * ``moe_sharded`` — sort-based dispatch inside ``shard_map``.  Experts are
@@ -29,7 +33,10 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.distributed import context as dctx
+from repro.kernels.routed_experts.ops import blocks_read, routed_experts
 from repro.models.modules import activation, mlp, pdtype
+
+EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
 
 
 def init_moe(key, cfg: ModelConfig) -> dict:
@@ -280,6 +287,31 @@ def _held_choices(idx, cfg: ModelConfig, valid):
     return sel
 
 
+def _held_index(idx, cfg: ModelConfig, valid):
+    """(T, k) index of each choice among the held experts; -1 where the
+    expert is held elsewhere or the token is not ``valid``."""
+    e = idx - cfg.moe.first_held
+    e = jnp.where((e >= 0) & (e < cfg.moe.held), e, -1)
+    return e if valid is None else jnp.where(valid.reshape(-1, 1), e, -1)
+
+
+def reads_routed(rows: int, cfg: ModelConfig) -> bool:
+    """Whether ``moe_held`` on ``rows`` tokens reads only the routed held
+    experts (the ``routed_experts`` kernel): their rows·k (token, choice)
+    pairs cannot reach every held expert.  Otherwise every row goes
+    through every held expert."""
+    return rows * cfg.moe.experts_per_token < cfg.moe.held
+
+
+def _at_layer(params, layer):
+    """``params`` whose expert weights are stacked over layers, taken at
+    ``layer``; as given where ``layer`` is None."""
+    if layer is None:
+        return params
+    return {n: (jax.lax.dynamic_index_in_dim(v, layer, keepdims=False)
+                if n in EXPERT_WEIGHTS else v) for n, v in params.items()}
+
+
 def held_rows(params, x, cfg: ModelConfig, valid=None):
     """(pairs, routed) of ``moe_held`` on ``x``: the (token, held expert)
     pairs that carry a weight, those of ``valid`` tokens whose top k take
@@ -293,36 +325,62 @@ def held_rows(params, x, cfg: ModelConfig, valid=None):
     return rows, jnp.asarray(n * m.experts_per_token, jnp.int32)
 
 
-def moe_held(params, x, cfg: ModelConfig, valid=None):
+def held_blocks(params, x, cfg: ModelConfig, valid=None):
+    """The held experts whose weights ``moe_held`` on ``x`` reads: the
+    distinct ones the ``valid`` tokens routed to, or every held expert
+    where every row goes through each (``reads_routed``)."""
+    m = cfg.moe
+    xf = x.reshape(-1, x.shape[-1])
+    if not reads_routed(xf.shape[0], cfg):
+        return jnp.int32(m.held)
+    _, idx, _ = _route(xf, params["router"], m.experts_per_token)
+    return blocks_read(_held_index(idx, cfg, valid), m.held)
+
+
+def moe_held(params, x, cfg: ModelConfig, valid=None, layer=None):
     """The part of the MoE layer that the held experts give, dropless.
 
     Softmax over the router's ``n_experts``, top-k, the k weights
-    renormalised; every token's held choices are computed, none dropped.
-    Each token goes through every held expert and each result is
-    weighted by the token's gate on that expert, which is 0 where the
-    expert is not among its top k or the token is not ``valid`` (B,S)
-    (default: all).  One form for every shape: on a TPU v5e it beat
-    ``ragged_dot`` over the pairs sorted by expert both at one decode
-    token and at a 512-row chunk (PERF.md, section 6)."""
+    renormalised; every token's held choices are computed, none dropped,
+    and those of tokens not ``valid`` (B,S) (default: all) carry no
+    weight.  ``layer``: where given, the expert weights are stacked over
+    layers and it picks this layer's.
+
+    Two forms of one computation, chosen by shape (``reads_routed``):
+    where the pairs cannot reach every held expert, the ``routed_experts``
+    kernel reads only the routed experts' weights; otherwise each token
+    goes through every held expert and each result is weighted by the
+    token's gate on that expert, 0 off its top k.  On a TPU v5e that
+    dense form beat ``ragged_dot`` over the pairs sorted by expert both
+    at one decode token and at a 512-row chunk (PERF.md, section 6)."""
     m = cfg.moe
     B, S, D = x.shape
     xf = x.reshape(B * S, D)
     gate, idx, probs = _route(xf, params["router"], m.experts_per_token)
-    g = jnp.einsum("tk,tke->te", gate, _held_choices(idx, cfg, valid))
     act = activation(cfg.act)
-    h = act(jnp.einsum("td,edf->tef", xf, params["w_gate"])) \
-        * jnp.einsum("td,edf->tef", xf, params["w_up"])
-    h = (h.astype(jnp.float32) * g[:, :, None]).astype(x.dtype)
-    y = jnp.einsum("tef,efd->td", h, params["w_down"]).reshape(B, S, D)
+    if reads_routed(B * S, cfg):
+        y = routed_experts(xf, gate, _held_index(idx, cfg, valid),
+                           *(params[n] for n in EXPERT_WEIGHTS), act=act,
+                           layer=layer)
+    else:
+        p = _at_layer(params, layer)
+        g = jnp.einsum("tk,tke->te", gate, _held_choices(idx, cfg, valid))
+        h = act(jnp.einsum("td,edf->tef", xf, p["w_gate"])) \
+            * jnp.einsum("td,edf->tef", xf, p["w_up"])
+        h = (h.astype(jnp.float32) * g[:, :, None]).astype(x.dtype)
+        y = jnp.einsum("tef,efd->td", h, p["w_down"])
+    y = y.reshape(B, S, D)
     if "dense" in params:
         y = y + mlp(params["dense"], x, cfg.act)
     return y, _aux_loss(probs, idx, m.n_experts)
 
 
 def moe_layer(params, x, cfg: ModelConfig, decode: bool = False,
-              valid=None):
+              valid=None, layer=None):
     """The expert layer: sharded over a mesh where one is set, else the
-    held share on this device (``moe_held``), for every caller."""
+    held share on this device (``moe_held``), for every caller.
+    ``layer``: where given, the expert weights are stacked over layers
+    and it picks this layer's."""
     if dctx.current() is not None:
-        return moe_sharded(params, x, cfg, decode=decode)
-    return moe_held(params, x, cfg, valid)
+        return moe_sharded(_at_layer(params, layer), x, cfg, decode=decode)
+    return moe_held(params, x, cfg, valid, layer)

@@ -164,8 +164,11 @@ def _layer_seq(p, x, cfg: ModelConfig, kind: str, positions, mask_mode,
     return x, aux, cache
 
 
-def _layer_decode(p, x, cfg: ModelConfig, kind: str, cache, pos, prefix_len):
-    """x: (B,1,D); returns (x, new_cache)."""
+def _layer_decode(p, x, cfg: ModelConfig, kind: str, cache, pos, prefix_len,
+                  layer=None):
+    """x: (B,1,D); returns (x, new_cache).  ``layer``: where given, the
+    expert weights in ``p["moe"]`` are stacked over layers and it picks
+    this layer's."""
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
     if kind in (ATTN_GLOBAL, ATTN_LOCAL):
         self_cache = {k: v for k, v in cache.items()
@@ -185,10 +188,12 @@ def _layer_decode(p, x, cfg: ModelConfig, kind: str, cache, pos, prefix_len):
             x = x + ox
         h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
         if "moe" in p:
-            f, _ = moe_mod.moe_layer(p["moe"], h2, cfg, decode=True)
+            f, _ = moe_mod.moe_layer(p["moe"], h2, cfg, decode=True,
+                                     layer=layer)
             if "experts" in cache:
                 new_cache["experts"] = _count_experts(
-                    cache["experts"], 1, *moe_mod.held_rows(p["moe"], h2, cfg))
+                    cache["experts"], 1, *moe_mod.held_rows(p["moe"], h2, cfg),
+                    moe_mod.held_blocks(p["moe"], h2, cfg))
         else:
             f = mlp(p["mlp"], h2, cfg.act)
         if cfg.post_norms:
@@ -342,19 +347,27 @@ def forward_decode(params, cfg: ModelConfig, cache, tokens, pos):
         x = x + _sin_at(pos, cfg.d_model, x.dtype)
     prefix_len = cfg.n_prefix_tokens
     x = constrain(x, ("batch", "seq", None))
+    blocks, experts = params["blocks"], None
+    if cfg.moe is not None:
+        blocks, experts = _split_experts(blocks)
 
     def block_fn(carry, xs):
         x, = carry
-        bp, bc = xs
+        bp, bc, *layer = xs
         new_caches = []
         for j, kind in enumerate(cfg.layer_pattern):
-            x, nc = _layer_decode(bp[j], x, cfg, kind, bc[j], pos, prefix_len)
+            p, i = bp[j], None
+            if experts is not None and "moe" in p:
+                p, i = {**p, "moe": {**p["moe"], **experts[j]}}, layer[0]
+            x, nc = _layer_decode(p, x, cfg, kind, bc[j], pos, prefix_len, i)
             new_caches.append(nc)
         return (x,), tuple(new_caches)
 
     if cfg.n_superblocks > 0:
-        (x,), new_block_caches = jax.lax.scan(
-            block_fn, (x,), (params["blocks"], cache["blocks"]))
+        xs = (blocks, cache["blocks"])
+        if experts is not None:
+            xs += (jnp.arange(cfg.n_superblocks, dtype=jnp.int32),)
+        (x,), new_block_caches = jax.lax.scan(block_fn, (x,), xs)
     else:
         new_block_caches = ()
     new_tail = []
@@ -364,6 +377,22 @@ def forward_decode(params, cfg: ModelConfig, cache, tokens, pos):
         new_tail.append(nc)
     logits = _unembed(params, cfg, x)
     return logits, {"blocks": new_block_caches, "tail": tuple(new_tail)}
+
+
+def _split_experts(blocks):
+    """(the scanned blocks without their expert weights, each pattern
+    position's expert weight stacks).  Decode hands the expert layer the
+    whole stacks and the layer's index: a Pallas operand sliced out of a
+    stack per layer would first be copied out whole, every layer."""
+    rest, stacks = [], []
+    for bp in blocks:
+        moe = bp.get("moe", {})
+        stacks.append({n: moe[n] for n in moe_mod.EXPERT_WEIGHTS if n in moe})
+        if moe:
+            bp = {**bp, "moe": {n: v for n, v in moe.items()
+                                if n not in moe_mod.EXPERT_WEIGHTS}}
+        rest.append(bp)
+    return tuple(rest), tuple(stacks)
 
 
 def _layer_chunk(p, x, cfg: ModelConfig, kind: str, cache, positions, last,
@@ -440,15 +469,18 @@ def _sin_at(pos, d, dtype):
 # chunk and decode programs
 # ---------------------------------------------------------------------------
 EXPERT_COUNTS = ("expert_rows_prefill", "expert_rows_decode",
-                 "expert_pairs_routed")
+                 "expert_pairs_routed", "expert_blocks_decode")
 
 
-def _count_experts(counts, row: int, rows, routed):
-    """Add ``rows`` (token, held expert) pairs computed to counter ``row``
-    and ``routed`` pairs to ``expert_pairs_routed``."""
+def _count_experts(counts, row: int, rows, routed, blocks=None):
+    """Add ``rows`` (token, held expert) pairs computed to counter ``row``,
+    ``routed`` pairs to ``expert_pairs_routed`` and, where given,
+    ``blocks`` held experts read to ``expert_blocks_decode``."""
     add = jnp.zeros((len(EXPERT_COUNTS),), jnp.uint32)
     add = add.at[row].set(rows.astype(jnp.uint32))
-    add = add.at[-1].set(routed.astype(jnp.uint32))
+    add = add.at[2].set(routed.astype(jnp.uint32))
+    if blocks is not None:
+        add = add.at[3].set(blocks.astype(jnp.uint32))
     lo = counts[:, 0] + add
     return jnp.stack([lo, counts[:, 1] + (lo < add).astype(jnp.uint32)], 1)
 

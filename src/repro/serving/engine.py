@@ -20,8 +20,10 @@ program, across all lanes.  ``stats`` counts which way the prompt tokens
 went, and for MoE configurations the (token, held expert) pairs that
 carry a weight (``expert_rows_prefill``, ``expert_rows_decode``, over
 every layer, a chunk's padding excluded, a decode step's every lane
-included) and the pairs routed (``expert_pairs_routed``, tokens times k
-times layers).  Those
+included), the pairs routed (``expert_pairs_routed``, tokens times k
+times layers) and the held experts whose weights decode read
+(``expert_blocks_decode``, over every layer and step: the distinct ones
+routed to where decode takes the routed-experts kernel, else all).  Those
 are added to on the device, inside the two programs, and copied to the
 host when ``stats`` is read.  The first token comes from the logits of
 the prompt's last position either way.

@@ -44,6 +44,7 @@ def test_kernel_phase_at_small_widths(chip_smoke):
                                      hd=128, window=128),
         "rglru_scan": dict(B=1, S=256, C=256),
         "wkv6": dict(BH=2, S=128, hd=64),
+        "routed_experts": dict(L=2, E=8, T=3, k=2, D=128, F=128),
     }
     assert set(small) == set(chip_smoke.KERNEL_CASES)
     cases = {name: (build, small[name], tol)

@@ -257,9 +257,85 @@ def test_expert_counters_match_the_reference_routing(served, P):
     assert 0.25 < share < 0.75
 
 
+@pytest.mark.parametrize("P", [21, 70])
+def test_dense_decode_reads_every_held_expert(served, P):
+    """Top 4 with 4 held: a decode token's pairs could reach every held
+    expert, so decode takes the dense form and counts all 4 read."""
+    cfg, _, out = served
+    eng, _, toks, _ = out[P]
+    assert not M.reads_routed(1, cfg)
+    assert eng.stats["expert_blocks_decode"] \
+        == (len(toks) - 1) * cfg.n_layers * cfg.moe.held
+
+
+@pytest.fixture(scope="module")
+def served_routed():
+    """As ``served``, at top 2 of 8 with experts 4-7 held: a decode
+    token's 2 pairs cannot reach all 4, so decode takes the kernel."""
+    cfg = _cfg(k=2, n_held=4, first_held=4)
+    assert M.reads_routed(1, cfg) and not M.reads_routed(32, cfg)
+    params = SERVE.make_weights(cfg, 2**33 + 7)
+    eng = ServingEngine(cfg, params, n_slots=1, max_len=128, eos_id=-1)
+    got = []
+    decode = eng._decode
+
+    def keep(*a):
+        logits, cache = decode(*a)
+        got.append(np.asarray(logits[0, -1], np.float32))
+        return logits, cache
+    eng._decode = keep
+    prompt = np.random.default_rng(4).integers(
+        2, cfg.vocab_size, 45).astype(np.int32)
+    eng.submit(Request(0, prompt, max_new=6))
+    req = eng.run_until_done()[0]
+    return cfg, params, eng, prompt, req.tokens_out, got
+
+
+def test_routed_decode_logits_match_the_reference(served_routed):
+    """The kernel through the engine (two chunks, then five decode steps
+    through the cache) against the reference's one pass, as
+    ``test_served_logits_match_the_reference``."""
+    cfg, params, _, prompt, toks, got = served_routed
+    P = len(prompt)
+    seq = np.concatenate([prompt, np.asarray(toks[:-1], np.int32)])
+    want = np.asarray(REF.logits(params, dataclasses.asdict(cfg), seq))[P - 1:]
+    np.testing.assert_allclose(np.stack(got[1:]), want, atol=1e-3)
+    assert list(want.argmax(-1)) == toks
+
+
+def test_expert_blocks_match_the_reference_routing(served_routed):
+    """One token a step: the distinct held experts the kernel reads are
+    that token's held choices, summed over layers and steps, which is
+    ``expert_rows_decode``."""
+    cfg, params, eng, prompt, toks, _ = served_routed
+    P = len(prompt)
+    seq = np.concatenate([prompt, np.asarray(toks[:-1], np.int32)])
+    per_pos = REF.held_pairs(params, dataclasses.asdict(cfg), seq)
+    stats = eng.stats
+    assert stats["expert_blocks_decode"] == per_pos[P:].sum() > 0
+    assert stats["expert_blocks_decode"] == stats["expert_rows_decode"]
+    assert stats["expert_blocks_decode"] \
+        < (len(toks) - 1) * cfg.n_layers * cfg.moe.held
+
+
+def test_held_blocks_count_shared_experts_once():
+    """Three tokens, two of them alike: the blocks read are the distinct
+    held experts of their routing, fewer than the held pairs."""
+    cfg = _cfg(k=2)
+    p = _layer_params(cfg)
+    x = _x(cfg, T=6)[:1, :3]
+    x = x.at[0, 2].set(x[0, 0])
+    assert M.reads_routed(3, cfg)
+    _, idx = jax.lax.top_k(x.reshape(-1, cfg.d_model) @ p["router"], 2)
+    want = len(set(np.asarray(idx).ravel().tolist()))
+    rows, _ = M.held_rows(p, x, cfg)
+    assert int(M.held_blocks(p, x, cfg)) == want < int(rows) == 6
+
+
 def test_counters_carry_past_32_bits():
-    from repro.models.transformer import _count_experts, expert_counts
-    c = jnp.zeros((3, 2), jnp.uint32).at[0, 0].set(2**32 - 3)
+    from repro.models.transformer import (EXPERT_COUNTS, _count_experts,
+                                          expert_counts)
+    c = jnp.zeros((len(EXPERT_COUNTS), 2), jnp.uint32).at[0, 0].set(2**32 - 3)
     c = _count_experts(c, 0, jnp.int32(5), jnp.int32(7))
     cache = {"blocks": ({"experts": c[None]},), "tail": ({"experts": c},)}
     got = expert_counts(cache)

@@ -24,6 +24,11 @@ def one_chip():
 
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("TPU_LOG_DIR", "disabled")
+        # the process is on the CPU, where kernels inside a model step
+        # would take the interpreter: compile them with Mosaic here
+        from repro.kernels.routed_experts import kernel as routed
+        mp.setattr(routed, "resolve_interpret",
+                   lambda interpret: bool(interpret))
         try:
             topo = topologies.get_topology_desc(platform="tpu",
                                                 topology_name="v5e:2x2")
@@ -127,11 +132,9 @@ def test_danube_prefill_chunk_compiles_at_engine_shapes(one_chip):
         + mem.temp_size_in_bytes < V5E_HBM_BYTES
 
 
-def test_mellum_share_programs_compile_at_engine_shapes(one_chip):
-    """The benchmark's ``mellum2-12b-a2.5b-ep2`` (20 layers, 32 of 64
-    experts held, max_len 4096, one slot): the prompt chunk and the decode
-    step compile for a v5e, each within one chip's memory beside its
-    9.69 GB of weights."""
+def _mellum_share(one_chip):
+    """(engine, parameter shapes, cache shapes, weight bytes) of the
+    benchmark's ``mellum2-12b-a2.5b-ep2`` on the described chip."""
     import json
     import sys
     from pathlib import Path
@@ -147,12 +150,21 @@ def test_mellum_share_programs_compile_at_engine_shapes(one_chip):
                     "mellum2-12b-a2.5b-ep2.json").read_text())
     cfg = common.load_module("runners", "serve").model_config(c)
     eng = ServingEngine(cfg, None, n_slots=1, max_len=c["max_len"])
-    assert eng.chunk == 512
     on_chip = lambda t: _spec(one_chip, t.shape, t.dtype)
     params = jax.tree.map(on_chip, jax.eval_shape(
         lambda k: init_params(k, cfg), jax.random.PRNGKey(0)))
     cache = jax.tree.map(on_chip, eng.cache)
     weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    return eng, params, cache, weights
+
+
+def test_mellum_share_programs_compile_at_engine_shapes(one_chip):
+    """The benchmark's ``mellum2-12b-a2.5b-ep2`` (20 layers, 32 of 64
+    experts held, max_len 4096, one slot): the prompt chunk and the decode
+    step compile for a v5e, each within one chip's memory beside its
+    9.69 GB of weights."""
+    eng, params, cache, weights = _mellum_share(one_chip)
+    assert eng.chunk == 512
     assert 9.6e9 < weights < 9.8e9
     i32 = _spec(one_chip, (), jnp.int32)
     for program, args in (
@@ -163,3 +175,27 @@ def test_mellum_share_programs_compile_at_engine_shapes(one_chip):
         assert mem.argument_size_in_bytes >= weights
         assert mem.argument_size_in_bytes + mem.output_size_in_bytes \
             + mem.temp_size_in_bytes < V5E_HBM_BYTES
+
+
+def test_mellum_decode_reads_the_expert_stacks_in_place(one_chip):
+    """Mellum's decode step takes the routed-experts kernel, which reads a
+    layer's routed experts straight out of the [5, 32, ...] stacks: the
+    compiled program holds Mosaic kernels and writes no buffer of one
+    layer's held experts (no copy, dynamic-slice or fusion that makes a
+    bf16[32,2304,896] or bf16[32,896,2304]), and fits one chip with
+    less than 64 MB of temporaries."""
+    import re
+
+    eng, params, cache, weights = _mellum_share(one_chip)
+    c = eng._decode_step.lower(
+        params, cache, _spec(one_chip, (1, 1), jnp.int32),
+        _spec(one_chip, (), jnp.int32)).compile()
+    text = c.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    layer_copy = re.compile(r"= bf16\[32,(2304,896|896,2304)\]")
+    assert not [line for line in text.splitlines() if layer_copy.search(line)]
+    mem = c.memory_analysis()
+    assert mem.argument_size_in_bytes >= weights
+    assert mem.temp_size_in_bytes < 64e6
+    assert mem.argument_size_in_bytes + mem.output_size_in_bytes \
+        + mem.temp_size_in_bytes < V5E_HBM_BYTES
