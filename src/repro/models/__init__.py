@@ -4,6 +4,7 @@ from repro.models.transformer import (  # noqa: F401
     forward_prefill,
     forward_decode,
     forward_chunk,
+    prefill_chunk_len,
     init_cache,
     expert_counts,
 )

@@ -435,7 +435,8 @@ def _write_rows(buf, rows, lane, s0, last, ring: bool):
 def attention_chunk(params, x, cfg: ModelConfig, kind: str, cache: dict,
                     positions, last, lane):
     """x: (1,C,D), one lane's prompt tokens at ``positions`` (C,) =
-    pos..pos+C-1; cache holds K/V (B,L,K,hd), unquantised.
+    pos..pos+C-1; cache holds K/V (B,L,K,hd), unquantised (the chunk
+    rule is ``transformer.prefill_chunk_len``).
 
     Partials merged by log-sum-exp: the chunk's queries against its own
     K/V, causal, then against the lane's cache before this chunk is

@@ -6,19 +6,26 @@ Layers are stacked as ``n_superblocks`` repetitions of the pattern scanned
 with ``lax.scan`` (compact HLO at 94 layers) plus an unrolled tail for
 non-divisible depths.
 
+One layer body, ``_layer``, and one walk over the superblocks and the
+tail, ``_walk``, serve the four forward entry points, each with a mode:
+``Seq`` (train, prefill, the encoder), ``Decode`` or ``Chunk``.  The
+mode picks the token mixer (``_mix``) and what follows from it: the cache
+entry, cross-attention's source, the expert layer's valid rows and
+counters, and the whole expert stacks decode hands its layers.
+
 Entry points:
     init_params(key, cfg)                        -> params pytree
     forward_train(params, cfg, batch)            -> (logits, aux_loss)
     forward_prefill(params, cfg, batch)          -> (logits, cache)
     forward_decode(params, cfg, cache, tok, pos) -> (logits, cache)
     forward_chunk(params, cfg, cache, tok, pos, last, lane) -> (logits, cache)
+    prefill_chunk_len(cfg, max_len)              -> chunk length, 0 for none
     init_cache(cfg, batch, seq_len)              -> cache pytree
     expert_counts(cache)                         -> {counter: int}
 """
 from __future__ import annotations
 
-import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -103,122 +110,127 @@ def init_params(key, cfg: ModelConfig) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# single-layer application (sequence mode)
+# one layer, in every mode
 # ---------------------------------------------------------------------------
-def _layer_seq(p, x, cfg: ModelConfig, kind: str, positions, mask_mode,
-               prefix_len, enc_out=None, want_cache=False, seq_exact=False):
-    """Returns (x, aux, cache_entry)."""
+class Seq(NamedTuple):
+    """Train, prefill and the encoder: the whole sequence at once."""
+    positions: jax.Array                  # (B,S)
+    mask_mode: str
+    prefix_len: int
+    enc_out: Optional[tuple] = None       # (encoder output, its positions)
+    want_cache: bool = False
+    seq_exact: bool = False
+
+
+class Decode(NamedTuple):
+    """One token a lane at position ``pos``, against the cache."""
+    pos: jax.Array
+    prefix_len: int
+
+
+class Chunk(NamedTuple):
+    """C prompt tokens of lane ``lane`` at ``positions`` (C,), against
+    that lane's cache; rows past ``last`` are padding."""
+    positions: jax.Array
+    last: jax.Array
+    lane: jax.Array
+
+
+def _mix(p, h, cfg: ModelConfig, kind: str, mode, cache):
+    """The token mixer of a ``kind`` layer in ``mode``: (out, cache
+    entry).  In ``Seq`` an attention layer's entry is its full K/V, which
+    ``_layer`` packs once the layer is done."""
+    seq = isinstance(mode, Seq)
+    if kind in (ATTN_GLOBAL, ATTN_LOCAL):
+        if seq:
+            o, (k, v) = attn.attention_seq(p["attn"], h, cfg, kind,
+                                           mode.positions, mode.mask_mode,
+                                           mode.prefix_len)
+            return o, {"k": k, "v": v}
+        if isinstance(mode, Decode):
+            return attn.attention_decode(p["attn"], h, cfg, kind, cache,
+                                         mode.pos, mode.prefix_len)
+        return attn.attention_chunk(p["attn"], h, cfg, kind, cache,
+                                    mode.positions, mode.last, mode.lane)
+    if kind == RGLRU:
+        if seq:
+            o, h_last, conv_tail = rglru_mod.rglru_seq(p["rec"], h, cfg)
+            return o, {"h": h_last, "conv": conv_tail}
+        return rglru_mod.rglru_decode(p["rec"], h, cfg, cache)
+    if kind == RWKV:
+        if seq:
+            fn = rwkv_mod.wkv_scan if mode.seq_exact else rwkv_mod.wkv_chunked
+            o, st, tm_x = fn(p["tm"], h, cfg)
+        else:
+            o, st, tm_x = rwkv_mod.time_mix_decode(p["tm"], h, cfg, cache)
+        return o, {"state": st, "tm_x": tm_x}
+    raise ValueError(kind)
+
+
+def _layer(p, x, cfg: ModelConfig, kind: str, mode, cache=None, layer=None):
+    """One layer of any kind in any mode: (x, aux loss, cache entry).
+
+    Pre-norm, token mixer (``_mix``), ``post_ln1``, residual; cross-
+    attention where ``ln_x`` is present; ``ln2``, the FFN (experts with
+    their counters, RWKV's channel mix or the MLP), ``post_ln2``,
+    residual.  ``cache``: the layer's entry, in decode and chunk.
+    ``layer``: where given, the expert weights in ``p["moe"]`` are stacked
+    over layers and it picks this layer's."""
+    seq, decode = isinstance(mode, Seq), isinstance(mode, Decode)
     aux = jnp.zeros((), jnp.float32)
-    cache = {}
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
-    if kind in (ATTN_GLOBAL, ATTN_LOCAL):
-        o, (k, v) = attn.attention_seq(p["attn"], h, cfg, kind, positions,
-                                       mask_mode, prefix_len)
-        if cfg.post_norms:
-            o = rms_norm(o, p["post_ln1"], cfg.norm_eps)
-        x = x + o
-        if "ln_x" in p:  # whisper decoder cross-attention
-            hx = rms_norm(x, p["ln_x"], cfg.norm_eps)
-            enc, enc_pos = enc_out
+    o, entry = _mix(p, h, cfg, kind, mode, cache)
+    if "post_ln1" in p:
+        o = rms_norm(o, p["post_ln1"], cfg.norm_eps)
+    x = x + o
+    if "ln_x" in p:  # whisper decoder cross-attention
+        hx = rms_norm(x, p["ln_x"], cfg.norm_eps)
+        if seq:
             ox, (ck, cv) = attn.attention_seq(
-                p["xattn"], hx, cfg, ATTN_GLOBAL, positions, "bidir",
-                kv_override=(enc, enc_pos))
-            x = x + ox
-            if want_cache:
-                cache["ck"], cache["cv"] = ck, cv
-        h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
-        if "moe" in p:
-            f, aux = moe_mod.moe_layer(p["moe"], h2, cfg)
+                p["xattn"], hx, cfg, ATTN_GLOBAL, mode.positions, "bidir",
+                kv_override=mode.enc_out)
+            entry.update(ck=ck, cv=cv)
         else:
-            f = mlp(p["mlp"], h2, cfg.act)
-        if cfg.post_norms:
-            f = rms_norm(f, p["post_ln2"], cfg.norm_eps)
-        x = x + f
-        if want_cache:
-            if kind == ATTN_LOCAL and cfg.window_size:
-                ring = attn.build_ring_cache(k, v, cfg.window_size,
-                                             cfg.kv_quant)
-                cache.update(ring)
-            else:
-                cache.update(attn.pack_kv(k, v, cfg.kv_quant))
-    elif kind == RGLRU:
-        o, h_last, conv_tail = rglru_mod.rglru_seq(p["rec"], h, cfg)
-        x = x + o
-        h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
-        x = x + mlp(p["mlp"], h2, cfg.act)
-        if want_cache:
-            cache = {"h": h_last, "conv": conv_tail}
-    elif kind == RWKV:
-        fn = rwkv_mod.wkv_scan if seq_exact else rwkv_mod.wkv_chunked
-        o, st, x_last_tm = fn(p["tm"], h, cfg)
-        x = x + o
-        h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
-        o2, x_last_cm = rwkv_mod.channel_mix(p["cm"], h2)
-        x = x + o2
-        if want_cache:
-            cache = {"state": st, "tm_x": x_last_tm, "cm_x": x_last_cm}
-    # sequence-parallel residual boundary: the scan carry (and the per-layer
-    # activation checkpoints it implies for backward) stays sharded over the
-    # model axis, Megatron-SP style.
-    x = constrain(x, ("batch", "act_seq", None))
-    return x, aux, cache
-
-
-def _layer_decode(p, x, cfg: ModelConfig, kind: str, cache, pos, prefix_len,
-                  layer=None):
-    """x: (B,1,D); returns (x, new_cache).  ``layer``: where given, the
-    expert weights in ``p["moe"]`` are stacked over layers and it picks
-    this layer's."""
-    h = rms_norm(x, p["ln1"], cfg.norm_eps)
-    if kind in (ATTN_GLOBAL, ATTN_LOCAL):
-        self_cache = {k: v for k, v in cache.items()
-                      if k in ("k", "v", "ksc", "vsc")}
-        o, nc = attn.attention_decode(p["attn"], h, cfg, kind, self_cache,
-                                      pos, prefix_len)
-        if cfg.post_norms:
-            o = rms_norm(o, p["post_ln1"], cfg.norm_eps)
-        x = x + o
-        new_cache = dict(cache)
-        new_cache.update(nc)
-        if "ln_x" in p:
-            hx = rms_norm(x, p["ln_x"], cfg.norm_eps)
-            ox, _ = attn.attention_decode(
-                p["xattn"], hx, cfg, ATTN_GLOBAL,
-                {"ck": cache["ck"], "cv": cache["cv"]}, pos, cross=True)
-            x = x + ox
-        h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
-        if "moe" in p:
-            f, _ = moe_mod.moe_layer(p["moe"], h2, cfg, decode=True,
-                                     layer=layer)
-            if "experts" in cache:
-                new_cache["experts"] = _count_experts(
-                    cache["experts"], 1, *moe_mod.held_rows(p["moe"], h2, cfg),
-                    moe_mod.held_blocks(p["moe"], h2, cfg))
-        else:
-            f = mlp(p["mlp"], h2, cfg.act)
-        if cfg.post_norms:
-            f = rms_norm(f, p["post_ln2"], cfg.norm_eps)
-        x = x + f
-    elif kind == RGLRU:
-        o, nc = rglru_mod.rglru_decode(p["rec"], h, cfg, cache)
-        x = x + o
-        h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
-        x = x + mlp(p["mlp"], h2, cfg.act)
-        new_cache = nc
-    elif kind == RWKV:
-        o, st, tm_x = rwkv_mod.time_mix_decode(p["tm"], h, cfg, cache)
-        x = x + o
-        h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
-        o2, cm_x = rwkv_mod.channel_mix(p["cm"], h2, cache["cm_x"])
-        x = x + o2
-        new_cache = {"state": st, "tm_x": tm_x, "cm_x": cm_x}
+            ox, _ = attn.attention_decode(p["xattn"], hx, cfg, ATTN_GLOBAL,
+                                          cache, mode.pos, cross=True)
+        x = x + ox
+    h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
+    if "moe" in p:
+        valid = None if seq or decode else \
+            (jnp.arange(x.shape[1]) <= mode.last)[None]
+        f, aux = moe_mod.moe_layer(p["moe"], h2, cfg, decode=decode,
+                                   valid=valid, layer=layer)
+        if cache is not None and "experts" in cache:
+            entry["experts"] = _count_experts(
+                cache["experts"], int(decode),
+                *moe_mod.held_rows(p["moe"], h2, cfg, valid),
+                moe_mod.held_blocks(p["moe"], h2, cfg) if decode else None)
+    elif "cm" in p:
+        f, entry["cm_x"] = rwkv_mod.channel_mix(
+            p["cm"], h2, None if seq else cache["cm_x"])
     else:
-        raise ValueError(kind)
-    return x, new_cache
+        f = mlp(p["mlp"], h2, cfg.act)
+    if "post_ln2" in p:
+        f = rms_norm(f, p["post_ln2"], cfg.norm_eps)
+    x = x + f
+    if seq:
+        if not mode.want_cache:
+            entry = {}
+        elif kind in (ATTN_GLOBAL, ATTN_LOCAL):
+            k, v = entry.pop("k"), entry.pop("v")
+            entry.update(
+                attn.build_ring_cache(k, v, cfg.window_size, cfg.kv_quant)
+                if kind == ATTN_LOCAL and cfg.window_size
+                else attn.pack_kv(k, v, cfg.kv_quant))
+        # sequence-parallel residual boundary: the scan carry (and the
+        # per-layer activation checkpoints it implies for backward) stays
+        # sharded over the model axis, Megatron-SP style.
+        x = constrain(x, ("batch", "act_seq", None))
+    return x, aux, entry
 
 
 # ---------------------------------------------------------------------------
-# stacks
+# the layer walk
 # ---------------------------------------------------------------------------
 def _remat_wrap(fn, cfg: ModelConfig):
     if cfg.remat == "none":
@@ -229,38 +241,61 @@ def _remat_wrap(fn, cfg: ModelConfig):
         fn, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
 
 
-def _run_stack(params, x, cfg: ModelConfig, positions, mask_mode, prefix_len,
-               enc_out=None, want_cache=False, remat=False, seq_exact=False):
-    """Scan superblocks then unrolled tail. Returns (x, aux, cache)."""
-    aux0 = jnp.zeros((), jnp.float32)
+def _walk(params, x, cfg: ModelConfig, mode, cache=None, remat=False):
+    """The scanned superblocks, then the unrolled tail, in ``mode``:
+    (x, aux, cache).  ``aux`` sums the layers' aux losses in ``Seq`` and
+    is None otherwise; ``cache`` holds each layer's entry."""
+    aux0 = jnp.zeros((), jnp.float32) if isinstance(mode, Seq) else None
+    blocks, stacks = params["blocks"], None
+    if isinstance(mode, Decode) and cfg.moe is not None:
+        blocks, stacks = _split_experts(blocks)
 
-    def block_fn(carry, bp):
-        x, aux = carry
+    def run(kinds, x, aux, ps, cs, layer=None):
+        """Layers ``kinds`` with parameters ``ps`` and cache entries
+        ``cs``; ``layer``: the superblock's index, where decode hands
+        the expert layers the whole stacks."""
         caches = []
-        for j, kind in enumerate(cfg.layer_pattern):
-            x, a, c = _layer_seq(bp[j], x, cfg, kind, positions, mask_mode,
-                                 prefix_len, enc_out, want_cache, seq_exact)
-            aux = aux + a
+        for j, kind in enumerate(kinds):
+            p, i = ps[j], None
+            if layer is not None and "moe" in p:
+                p, i = {**p, "moe": {**p["moe"], **stacks[j]}}, layer
+            x, a, c = _layer(p, x, cfg, kind, mode,
+                             None if cs is None else cs[j], i)
+            aux = None if aux is None else aux + a
             caches.append(c)
-        return (x, aux), tuple(caches)
+        return x, aux, tuple(caches)
+
+    def block_fn(carry, xs):
+        x, aux, caches = run(cfg.layer_pattern, *carry, *xs)
+        return (x, aux), caches
 
     fn = _remat_wrap(block_fn, cfg) if remat else block_fn
     if cfg.n_superblocks > 0:
-        (x, aux), block_caches = jax.lax.scan(fn, (x, aux0), params["blocks"])
+        xs = (blocks, None if cache is None else cache["blocks"])
+        if stacks is not None:
+            xs += (jnp.arange(cfg.n_superblocks, dtype=jnp.int32),)
+        (x, aux), block_caches = jax.lax.scan(fn, (x, aux0), xs)
     else:
-        block_caches = ()
-        aux = aux0
+        block_caches, aux = (), aux0
+    x, aux, tail = run(cfg.tail_pattern, x, aux, params["tail"],
+                       None if cache is None else cache["tail"])
+    return x, aux, {"blocks": block_caches, "tail": tail}
 
-    tail_caches = []
-    for j, kind in enumerate(cfg.tail_pattern):
-        x, a, c = _layer_seq(params["tail"][j], x, cfg, kind, positions,
-                             mask_mode, prefix_len, enc_out, want_cache,
-                             seq_exact)
-        aux = aux + a
-        tail_caches.append(c)
-    cache = {"blocks": block_caches, "tail": tuple(tail_caches)} \
-        if want_cache else None
-    return x, aux, cache
+
+def _split_experts(blocks):
+    """(the scanned blocks without their expert weights, each pattern
+    position's expert weight stacks).  Decode hands the expert layer the
+    whole stacks and the layer's index: a Pallas operand sliced out of a
+    stack per layer would first be copied out whole, every layer."""
+    rest, stacks = [], []
+    for bp in blocks:
+        moe = bp.get("moe", {})
+        stacks.append({n: moe[n] for n in moe_mod.EXPERT_WEIGHTS if n in moe})
+        if moe:
+            bp = {**bp, "moe": {n: v for n, v in moe.items()
+                                if n not in moe_mod.EXPERT_WEIGHTS}}
+        rest.append(bp)
+    return tuple(rest), tuple(stacks)
 
 
 def _embed(params, cfg: ModelConfig, tokens):
@@ -288,15 +323,13 @@ def _encode(params, cfg: ModelConfig, frames):
     x = x + sinusoidal_pos_emb(S, D, x.dtype)[None]
     pos = jnp.broadcast_to(jnp.arange(S), (B, S))
     for p in params["enc_blocks"]:
-        x, _, _ = _layer_seq(
-            {k: v for k, v in p.items() if k not in ("ln_x", "xattn")},
-            x, cfg, ATTN_GLOBAL, pos, "bidir", 0)
+        x, _, _ = _layer(p, x, cfg, ATTN_GLOBAL, Seq(pos, "bidir", 0))
     return rms_norm(x, params["enc_norm"], cfg.norm_eps), pos
 
 
-def _prep_inputs(params, cfg: ModelConfig, batch):
-    """Embedding + frontend stub handling -> (x, positions, mask_mode,
-    prefix_len, enc_out)."""
+def _prep_inputs(params, cfg: ModelConfig, batch, **seq):
+    """Embedding + frontend stub handling -> (x, ``Seq`` mode with
+    ``seq``'s fields)."""
     tokens = batch["tokens"]
     B = tokens.shape[0]
     enc_out = None
@@ -316,28 +349,22 @@ def _prep_inputs(params, cfg: ModelConfig, batch):
     S = x.shape[1]
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
     x = constrain(x, ("batch", "act_seq", None))
-    return x, positions, mask_mode, prefix_len, enc_out
+    return x, Seq(positions, mask_mode, prefix_len, enc_out, **seq)
 
 
 # ---------------------------------------------------------------------------
 # public entry points
 # ---------------------------------------------------------------------------
 def forward_train(params, cfg: ModelConfig, batch, seq_exact: bool = False):
-    x, positions, mask_mode, prefix_len, enc_out = _prep_inputs(
-        params, cfg, batch)
-    x, aux, _ = _run_stack(params, x, cfg, positions, mask_mode, prefix_len,
-                           enc_out, want_cache=False, remat=True,
-                           seq_exact=seq_exact)
+    x, mode = _prep_inputs(params, cfg, batch, seq_exact=seq_exact)
+    x, aux, _ = _walk(params, x, cfg, mode, remat=True)
     return _unembed(params, cfg, x), aux
 
 
 def forward_prefill(params, cfg: ModelConfig, batch):
-    x, positions, mask_mode, prefix_len, enc_out = _prep_inputs(
-        params, cfg, batch)
-    x, _, cache = _run_stack(params, x, cfg, positions, mask_mode, prefix_len,
-                             enc_out, want_cache=True)
-    logits = _unembed(params, cfg, x[:, -1:])
-    return logits, cache
+    x, mode = _prep_inputs(params, cfg, batch, want_cache=True)
+    x, _, cache = _walk(params, x, cfg, mode)
+    return _unembed(params, cfg, x[:, -1:]), cache
 
 
 def forward_decode(params, cfg: ModelConfig, cache, tokens, pos):
@@ -345,116 +372,52 @@ def forward_decode(params, cfg: ModelConfig, cache, tokens, pos):
     x = _embed(params, cfg, tokens)
     if cfg.encoder_decoder:
         x = x + _sin_at(pos, cfg.d_model, x.dtype)
-    prefix_len = cfg.n_prefix_tokens
     x = constrain(x, ("batch", "seq", None))
-    blocks, experts = params["blocks"], None
-    if cfg.moe is not None:
-        blocks, experts = _split_experts(blocks)
-
-    def block_fn(carry, xs):
-        x, = carry
-        bp, bc, *layer = xs
-        new_caches = []
-        for j, kind in enumerate(cfg.layer_pattern):
-            p, i = bp[j], None
-            if experts is not None and "moe" in p:
-                p, i = {**p, "moe": {**p["moe"], **experts[j]}}, layer[0]
-            x, nc = _layer_decode(p, x, cfg, kind, bc[j], pos, prefix_len, i)
-            new_caches.append(nc)
-        return (x,), tuple(new_caches)
-
-    if cfg.n_superblocks > 0:
-        xs = (blocks, cache["blocks"])
-        if experts is not None:
-            xs += (jnp.arange(cfg.n_superblocks, dtype=jnp.int32),)
-        (x,), new_block_caches = jax.lax.scan(block_fn, (x,), xs)
-    else:
-        new_block_caches = ()
-    new_tail = []
-    for j, kind in enumerate(cfg.tail_pattern):
-        x, nc = _layer_decode(params["tail"][j], x, cfg, kind,
-                              cache["tail"][j], pos, prefix_len)
-        new_tail.append(nc)
-    logits = _unembed(params, cfg, x)
-    return logits, {"blocks": new_block_caches, "tail": tuple(new_tail)}
+    x, _, cache = _walk(params, x, cfg, Decode(pos, cfg.n_prefix_tokens),
+                        cache)
+    return _unembed(params, cfg, x), cache
 
 
-def _split_experts(blocks):
-    """(the scanned blocks without their expert weights, each pattern
-    position's expert weight stacks).  Decode hands the expert layer the
-    whole stacks and the layer's index: a Pallas operand sliced out of a
-    stack per layer would first be copied out whole, every layer."""
-    rest, stacks = [], []
-    for bp in blocks:
-        moe = bp.get("moe", {})
-        stacks.append({n: moe[n] for n in moe_mod.EXPERT_WEIGHTS if n in moe})
-        if moe:
-            bp = {**bp, "moe": {n: v for n, v in moe.items()
-                                if n not in moe_mod.EXPERT_WEIGHTS}}
-        rest.append(bp)
-    return tuple(rest), tuple(stacks)
+PREFILL_CHUNK = 512      # prompt tokens a chunk: compute-bound on a v5e
 
 
-def _layer_chunk(p, x, cfg: ModelConfig, kind: str, cache, positions, last,
-                 lane):
-    """x: (1,C,D) of one lane; an attention layer with a dense MLP or
-    experts, whose gates are 0 on the rows past ``last``."""
-    h = rms_norm(x, p["ln1"], cfg.norm_eps)
-    o, new_cache = attn.attention_chunk(p["attn"], h, cfg, kind, cache,
-                                        positions, last, lane)
-    if cfg.post_norms:
-        o = rms_norm(o, p["post_ln1"], cfg.norm_eps)
-    x = x + o
-    h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
-    if "moe" in p:
-        valid = (jnp.arange(x.shape[1]) <= last)[None]
-        f, _ = moe_mod.moe_layer(p["moe"], h2, cfg, valid=valid)
-        if "experts" in cache:
-            new_cache["experts"] = _count_experts(
-                cache["experts"], 0,
-                *moe_mod.held_rows(p["moe"], h2, cfg, valid))
-    else:
-        f = mlp(p["mlp"], h2, cfg.act)
-    if cfg.post_norms:
-        f = rms_norm(f, p["post_ln2"], cfg.norm_eps)
-    return x + f, new_cache
+def prefill_chunk_len(cfg: ModelConfig, max_len: int) -> int:
+    """The chunk length C for ``cfg`` (``forward_chunk``), or 0 where a
+    prompt is fed a token at a time through ``forward_decode``.
+
+    Chunks take stacks of global and local attention layers, with dense
+    MLPs or experts, and an unquantised cache, without an encoder or a
+    prefix frontend.  Any other configuration (recurrent layers, whose
+    chunk would have to scan from the cache's state; an int8 cache;
+    whisper; paligemma) gets 0.  C = min(``PREFILL_CHUNK``, ``max_len``,
+    every local layer's ring), so a chunk's slots are distinct even where
+    the ring wraps inside it."""
+    kinds = set(cfg.layer_pattern) | set(cfg.tail_pattern)
+    if not kinds <= {ATTN_GLOBAL, ATTN_LOCAL} or cfg.kv_quant \
+            or cfg.encoder_decoder or cfg.frontend or cfg.n_prefix_tokens:
+        return 0
+    c = min(PREFILL_CHUNK, max_len)
+    if ATTN_LOCAL in kinds:
+        c = min(c, cfg.window_size)
+    return c
 
 
 def forward_chunk(params, cfg: ModelConfig, cache, tokens, pos, last, lane):
     """C prompt tokens of lane ``lane`` at positions pos..pos+C-1, against
-    that lane's cache, for stacks of attention layers with dense MLPs or
-    experts.
+    that lane's cache, for the configurations ``prefill_chunk_len`` gives
+    a chunk length.
 
     tokens: (1,C); pos, last, lane: int32 scalars.  Rows past ``last``
-    are padding: computed, never written to the cache.  Returns the logits
-    of row ``last`` only, (1,1,V), and the cache with the chunk's K/V in
-    the lane's slots; every other lane is left as it was."""
+    are padding: computed, never written to the cache, and with gates of 0
+    in an expert layer.  Returns the logits of row ``last`` only, (1,1,V),
+    and the cache with the chunk's K/V in the lane's slots; every other
+    lane is left as it was."""
     x = _embed(params, cfg, tokens)
     positions = pos + jnp.arange(tokens.shape[1], dtype=jnp.int32)
-
-    def block_fn(carry, xs):
-        x, = carry
-        bp, bc = xs
-        new_caches = []
-        for j, kind in enumerate(cfg.layer_pattern):
-            x, nc = _layer_chunk(bp[j], x, cfg, kind, bc[j], positions, last,
-                                 lane)
-            new_caches.append(nc)
-        return (x,), tuple(new_caches)
-
-    if cfg.n_superblocks > 0:
-        (x,), new_block_caches = jax.lax.scan(
-            block_fn, (x,), (params["blocks"], cache["blocks"]))
-    else:
-        new_block_caches = ()
-    new_tail = []
-    for j, kind in enumerate(cfg.tail_pattern):
-        x, nc = _layer_chunk(params["tail"][j], x, cfg, kind,
-                             cache["tail"][j], positions, last, lane)
-        new_tail.append(nc)
+    x, _, cache = _walk(params, x, cfg, Chunk(positions, last, lane), cache)
     logits = _unembed(params, cfg,
                       jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1))
-    return logits, {"blocks": new_block_caches, "tail": tuple(new_tail)}
+    return logits, cache
 
 
 def _sin_at(pos, d, dtype):

@@ -8,20 +8,16 @@ serving counterpart of the Databelt runtime: the KV-cache slot is the
 
 A prompt of P tokens is fed to its own lane in ceil(P/C) chunks of C
 tokens through one compiled program, ``prefill_chunk``
-(``forward_chunk``), with C = min(``PREFILL_CHUNK``, every local layer's
-ring length, ``max_len``); the other lanes are left untouched.  That takes
-a stack of global and local attention layers, with dense MLPs or experts
-(the dropless held share of ``models/moe.py``, whose gates are 0 on a
-chunk's padding rows), and an unquantised cache, without an encoder or a
-prefix frontend.  Any other configuration (recurrent layers, whose
-chunk would have to scan from the cache's state; an int8 cache; whisper;
-paligemma) feeds its prompt one token at a time through the decode
-program, across all lanes.  ``stats`` counts which way the prompt tokens
-went, and for MoE configurations the (token, held expert) pairs that
-carry a weight (``expert_rows_prefill``, ``expert_rows_decode``, over
-every layer, a chunk's padding excluded, a decode step's every lane
-included), the pairs routed (``expert_pairs_routed``, tokens times k
-times layers) and the held experts whose weights decode read
+(``forward_chunk``); the other lanes are left untouched.  The model
+decides C: ``prefill_chunk_len`` in ``models/transformer.py`` gives it,
+or 0 for a configuration whose prompt is fed one token at a time through
+the decode program, across all lanes.  ``stats`` counts which way the
+prompt tokens went, and for MoE configurations the (token, held expert)
+pairs that carry a weight (``expert_rows_prefill``,
+``expert_rows_decode``, over every layer, a chunk's padding excluded, a
+decode step's every lane included), the pairs routed
+(``expert_pairs_routed``, tokens times k times layers) and the held
+experts whose weights decode read
 (``expert_blocks_decode``, over every layer and step: the distinct ones
 routed to where decode takes the routed-experts kernel, else all).  Those
 are added to on the device, inside the two programs, and copied to the
@@ -47,27 +43,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import ATTN_GLOBAL, ATTN_LOCAL, ModelConfig
+from repro.configs.base import ModelConfig
 from repro.models import (expert_counts, forward_chunk, forward_decode,
-                          init_cache)
+                          init_cache, prefill_chunk_len)
 from repro.models.modules import pdtype
 
 _NO_SPAN = contextlib.nullcontext()
-PREFILL_CHUNK = 512      # prompt tokens a chunk: compute-bound on a v5e
-
-
-def prefill_chunk_len(cfg: ModelConfig, max_len: int) -> int:
-    """The chunk length C for ``cfg``, or 0 where prompts are fed a token
-    at a time.  C never exceeds a local layer's ring, so a chunk's slots
-    are distinct even where the ring wraps inside it."""
-    kinds = set(cfg.layer_pattern) | set(cfg.tail_pattern)
-    if not kinds <= {ATTN_GLOBAL, ATTN_LOCAL} or cfg.kv_quant \
-            or cfg.encoder_decoder or cfg.frontend or cfg.n_prefix_tokens:
-        return 0
-    c = min(PREFILL_CHUNK, max_len)
-    if ATTN_LOCAL in kinds:
-        c = min(c, cfg.window_size)
-    return c
 
 
 @dataclass

@@ -10,9 +10,9 @@ import numpy as np
 import pytest
 
 from repro.configs.base import get_smoke_config, list_archs
-from repro.models import forward_prefill, init_params
-import repro.serving.engine as engine_mod
-from repro.serving.engine import Request, ServingEngine, prefill_chunk_len
+from repro.models import forward_prefill, init_params, prefill_chunk_len
+import repro.models.transformer as transformer
+from repro.serving.engine import Request, ServingEngine
 
 MAX_LEN = 128
 MAX_NEW = 4
@@ -71,7 +71,7 @@ def test_chunks_that_straddle_the_ring_end(models, monkeypatch, arch, n):
     """C = 12 against a ring of 32: the chunk at position 24 writes slots
     24-31 and 0-3; gemma's global layers read their 128 slots in blocks
     of 12 with a shorter last block."""
-    monkeypatch.setattr(engine_mod, "PREFILL_CHUNK", 12)
+    monkeypatch.setattr(transformer, "PREFILL_CHUNK", 12)
     cfg, params = models[arch]
     prompt = _prompt(n, cfg.vocab_size, seed=1)
     eng, served = _serve_alone(cfg, params, prompt)
