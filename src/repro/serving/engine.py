@@ -24,6 +24,33 @@ are added to on the device, inside the two programs, and copied to the
 host when ``stats`` is read.  The first token comes from the logits of
 the prompt's last position either way.
 
+Both programs also return the greedy token of each row's last position,
+``argmax`` of the float32 logits as int32 ``(rows, 1)``, so the host
+reads one small array a step and dispatches nothing else.  A decode step
+runs one step ahead of the host:
+
+1. step N is dispatched (or claimed, below);
+2. where its tokens are the whole input of step N+1 (every lane of the
+   batch is busy, or the prompt's lane is the batch) and every one of
+   those lanes goes on past this token whatever it is (``budget`` after
+   it at least 1, position below ``max_len - 1``), step N+1 is
+   dispatched at once on step N's device tokens and cache;
+3. only then does the host read step N's tokens and ``_emit`` them.
+
+After a prompt the same holds for the last chunk (or prompt step): the
+first decode step is queued from the program's own token output, and
+the first token is read from that program, not behind the step.  The
+next ``_decode`` call claims the step ahead only if it asks for exactly
+what that step ran: the same parameters and parent cache object, the
+same position and busy lanes, and host tokens equal to the device tokens
+it was fed.  Otherwise the step is dropped, its cache discarded and the
+parent's kept; so is one that what was emitted no longer asks for (an
+end-of-sequence token, an ``_emit`` that took another token).  A dropped
+step leaves no trace: recurrent state and expert counters advance by
+claimed steps only.  ``stats`` counts ``decode_steps_ahead`` (steps
+dispatched before their input tokens were read) and
+``decode_steps_dropped`` (those not claimed).
+
 With ``annotate=True`` the engine opens ``jax.profiler.TraceAnnotation``
 spans at its layer boundaries, stamped by the profiler on the clock of the
 device planes: ``serve.step`` (one decode step, admission included),
@@ -37,7 +64,7 @@ from __future__ import annotations
 import collections
 import contextlib
 from dataclasses import dataclass, field
-from typing import Deque, Dict, List, Optional
+from typing import Any, Deque, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -49,6 +76,22 @@ from repro.models import (expert_counts, forward_chunk, forward_decode,
 from repro.models.modules import pdtype
 
 _NO_SPAN = contextlib.nullcontext()
+
+
+def _greedy(logits):
+    """The greedy token of each row's last position, (rows, 1) int32."""
+    return jnp.argmax(logits[:, -1].astype(jnp.float32), axis=-1,
+                      keepdims=True).astype(jnp.int32)
+
+
+class _Ahead(NamedTuple):
+    """A decode step dispatched before the host read its input tokens."""
+    params: Any
+    parent: Any                   # the cache it was fed
+    tokens: jax.Array             # the (n_slots, 1) tokens it was fed
+    pos: np.int32
+    lanes: Tuple[int, ...]
+    out: tuple                    # (logits, cache, greedy tokens)
 
 
 @dataclass
@@ -77,13 +120,18 @@ class ServingEngine:
         self.cache = init_cache(cfg, n_slots, max_len, pdtype(cfg))
         self.chunk = prefill_chunk_len(cfg, max_len)
         self._counts = {"prefill_chunks": 0, "prompt_tokens_chunked": 0,
-                        "prompt_tokens_stepped": 0}
+                        "prompt_tokens_stepped": 0, "decode_steps_ahead": 0,
+                        "decode_steps_dropped": 0}
+        self._tok = None              # greedy tokens of the last model call
+        self._ahead: Optional[_Ahead] = None
 
         def decode_step(p, c, tok, pos):
-            return forward_decode(p, cfg, c, tok, pos)
+            logits, c = forward_decode(p, cfg, c, tok, pos)
+            return logits, c, _greedy(logits)
 
         def prefill_chunk(p, c, tok, pos, last, lane):
-            return forward_chunk(p, cfg, c, tok, pos, last, lane)
+            logits, c = forward_chunk(p, cfg, c, tok, pos, last, lane)
+            return logits, c, _greedy(logits)
         self._decode_step = jax.jit(decode_step)
         self._prefill_chunk = jax.jit(prefill_chunk)
         self.completed: List[Request] = []
@@ -114,17 +162,79 @@ class ServingEngine:
 
     def _decode(self, params, cache, tokens, pos, *chunk):
         """The engine's one entry for a model step, returning (logits,
-        cache): a (n_slots, 1) token block goes to ``decode_step``; one
-        lane's (1, C) block, with its ``last`` row and ``lane``, to
-        ``prefill_chunk``, whose logits are those of row ``last``."""
+        cache) and keeping the step's greedy tokens in ``_tok``: a
+        (n_slots, 1) token block goes to ``decode_step``, unless the step
+        ahead ran exactly that, which is then handed back; one lane's
+        (1, C) block, with its ``last`` row and ``lane``, to
+        ``prefill_chunk``, whose logits are those of row ``last``.  A step
+        ahead that is not handed back is dropped."""
+        ahead, self._ahead = self._ahead, None
         if chunk:
-            return self._prefill_chunk(params, cache, tokens, pos, *chunk)
-        return self._decode_step(params, cache, tokens, pos)
+            out = self._prefill_chunk(params, cache, tokens, pos, *chunk)
+        elif ahead is not None and self._claims(ahead, params, cache,
+                                                tokens, pos):
+            out, ahead = ahead.out, None
+        else:
+            out = self._decode_step(params, cache, jnp.asarray(tokens), pos)
+        if ahead is not None:
+            self._counts["decode_steps_dropped"] += 1
+        logits, cache, self._tok = out
+        return logits, cache
+
+    def _lanes(self) -> Tuple[int, ...]:
+        return tuple(i for i, r in enumerate(self.slots) if r is not None)
+
+    def _inputs(self, lanes):
+        """A decode step's host tokens, each busy lane's last token (0
+        elsewhere), and its position, the furthest of the lanes'."""
+        toks = np.zeros((self.n_slots, 1), np.int32)
+        for i in lanes:
+            r = self.slots[i]
+            toks[i, 0] = r.tokens_out[-1] if r.tokens_out else 0
+        # positions go in as strongly typed int32 here, in _run_ahead and
+        # in _feed_prompt, so all share one compiled decode program
+        return toks, np.int32(max((self.pos[i] for i in lanes), default=0))
+
+    def _claims(self, ahead: _Ahead, params, cache, tokens, pos) -> bool:
+        """Whether a decode step on these arguments is the one ahead ran;
+        its input tokens were read by the host before this is asked."""
+        return (params is ahead.params and cache is ahead.parent
+                and pos == ahead.pos and self._lanes() == ahead.lanes
+                and np.array_equal(np.asarray(tokens),
+                                   np.asarray(ahead.tokens)))
+
+    def _run_ahead(self, lanes):
+        """Dispatch the decode step after the model call whose greedy
+        tokens ``_tok`` holds for ``lanes``, before the host reads them,
+        where those tokens are its whole input and each lane goes on past
+        this token whatever it is."""
+        if len(lanes) < self.n_slots or not all(
+                self.budget[i] > 1 and self.pos[i] < self.max_len - 1
+                for i in lanes):
+            return
+        pos = np.int32(max(self.pos[i] for i in lanes))
+        self._ahead = _Ahead(self.params, self.cache, self._tok, pos,
+                             tuple(lanes), self._decode_step(
+                                 self.params, self.cache, self._tok, pos))
+        self._counts["decode_steps_ahead"] += 1
+
+    def _emit_greedy(self, lanes, rows):
+        """Read the last model call's greedy tokens and emit row ``rows[j]``
+        to lane ``lanes[j]``; then drop the step ahead if what was emitted
+        no longer asks for it."""
+        got = np.asarray(self._tok)
+        for i, row in zip(lanes, rows):
+            self._emit(i, int(got[row, 0]))
+        ahead = self._ahead
+        if ahead is not None and not self._claims(
+                ahead, self.params, self.cache, *self._inputs(self._lanes())):
+            self._ahead = None
+            self._counts["decode_steps_dropped"] += 1
 
     def _feed_prompt(self, i: int, req: Request):
         with (jax.profiler.TraceAnnotation("serve.prefill") if self.annotate
               else _NO_SPAN):
-            logits, P, C = None, len(req.prompt), self.chunk
+            P, C = len(req.prompt), self.chunk
             if C:
                 # positions, last row and lane go in as strongly typed
                 # int32, so every prompt length shares one program
@@ -132,29 +242,29 @@ class ServingEngine:
                     part = np.asarray(req.prompt[start:start + C], np.int32)
                     tok = np.zeros((1, C), np.int32)
                     tok[0, :len(part)] = part
-                    logits, self.cache = self._decode(
+                    _, self.cache = self._decode(
                         self.params, self.cache, tok, np.int32(start),
                         np.int32(len(part) - 1), np.int32(i))
                     self._counts["prefill_chunks"] += 1
                 self._counts["prompt_tokens_chunked"] += P
                 self.pos[i] = P
-                lane = 0
+                row = 0
             else:
                 for t in req.prompt:
                     tok = jnp.full((self.n_slots, 1), int(t), jnp.int32)
                     # only slot i's lane matters; the others decode a dummy
                     # token at slot i's position, which corrupts a busy
                     # lane (one scalar position per batch: ROADMAP queue 2)
-                    logits, self.cache = self._decode(
+                    _, self.cache = self._decode(
                         self.params, self.cache, tok, np.int32(self.pos[i]))
                     self.pos[i] += 1
                 self._counts["prompt_tokens_stepped"] += P
-                lane = i
+                row = i
             # the first token comes from the prompt's last logits; decode
             # steps then feed it back at position len(prompt)
-            if logits is not None:
-                self._emit(i, int(jnp.argmax(
-                    logits[lane, -1].astype(jnp.float32))))
+            if P:
+                self._run_ahead((i,))
+                self._emit_greedy((i,), (row,))
 
     def _emit(self, i: int, t: int):
         """Slot i's request takes token t; the slot frees when it is done."""
@@ -175,25 +285,16 @@ class ServingEngine:
         with (jax.profiler.TraceAnnotation("serve.step") if self.annotate
               else _NO_SPAN):
             self._admit()
-            active = [i for i, r in enumerate(self.slots) if r is not None]
-            if not active:
+            lanes = self._lanes()
+            if not lanes:
                 return 0
-            toks = np.zeros((self.n_slots, 1), np.int32)
-            for i in active:
-                r = self.slots[i]
-                toks[i, 0] = r.tokens_out[-1] if r.tokens_out else 0
-            # positions go in as strongly typed int32 here and in
-            # _feed_prompt, so both call sites share one compiled decode
-            # program
-            pos = np.int32(max(self.pos[i] for i in active))
-            logits, self.cache = self._decode(
-                self.params, self.cache, jnp.asarray(toks), pos)
-            nxt = np.asarray(jnp.argmax(logits[:, -1].astype(jnp.float32),
-                                        axis=-1))
-            for i in active:
+            _, self.cache = self._decode(self.params, self.cache,
+                                         *self._inputs(lanes))
+            for i in lanes:
                 self.pos[i] += 1
-                self._emit(i, int(nxt[i]))
-            return len(active)
+            self._run_ahead(lanes)
+            self._emit_greedy(lanes, lanes)
+            return len(lanes)
 
     def run_until_done(self, max_steps: int = 10_000) -> List[Request]:
         steps = 0

@@ -348,7 +348,8 @@ def test_dense_model_has_no_expert_counters():
     eng = ServingEngine(cfg, init_params(jax.random.PRNGKey(0), cfg),
                         n_slots=1, max_len=64)
     assert set(eng.stats) == {"prefill_chunks", "prompt_tokens_chunked",
-                              "prompt_tokens_stepped"}
+                              "prompt_tokens_stepped", "decode_steps_ahead",
+                              "decode_steps_dropped"}
 
 
 def test_benchmark_share_has_its_published_size():

@@ -131,9 +131,12 @@ def test_stats_count_chunks_and_tokens(models):
     for i, n in enumerate((31, 33, 67)):
         eng.submit(Request(i, _prompt(n, cfg.vocab_size), max_new=2))
     eng.run_until_done()
+    # two tokens a request: no lane goes on past its second, and a lane
+    # alone is not the whole batch of two, so no step runs ahead
     assert eng.stats == {"prefill_chunks": 1 + 2 + 3,
                          "prompt_tokens_chunked": 31 + 33 + 67,
-                         "prompt_tokens_stepped": 0}
+                         "prompt_tokens_stepped": 0,
+                         "decode_steps_ahead": 0, "decode_steps_dropped": 0}
 
 
 def test_recurrent_config_feeds_a_token_at_a_time():
@@ -144,7 +147,8 @@ def test_recurrent_config_feeds_a_token_at_a_time():
     assert len(eng.run_until_done()) == 1
     assert eng.chunk == 0
     assert eng.stats == {"prefill_chunks": 0, "prompt_tokens_chunked": 0,
-                         "prompt_tokens_stepped": 9}
+                         "prompt_tokens_stepped": 9,
+                         "decode_steps_ahead": 0, "decode_steps_dropped": 0}
     assert eng._prefill_chunk._cache_size() == 0
 
 
